@@ -20,7 +20,7 @@ argmin).
 
 ``--emit-json PATH`` instead writes a machine-keyed document of best-plan
 rows per backend (xla / matmul / pallas, the latter in interpret mode
-off-TPU) — the committed ``BENCH_tuner.json`` baseline.  ``--gate BASELINE``
+on the CPU) — the committed ``BENCH_tuner.json`` baseline.  ``--gate BASELINE``
 compares the freshly measured rows against that baseline and exits nonzero
 when any backend regressed by more than 20% *relative to the xla backend on
 the same grid in the same run* (absolute wall times are machine-specific;
@@ -176,7 +176,7 @@ def best_plan_rows(shapes=JSON_SHAPES) -> dict:
 
     For each grid and each tuner backend, the cost model picks that
     backend's best candidate (decomp x mesh-axis order x chunk schedule)
-    and ``measure_candidate`` times its compiled executable.  Off-TPU the
+    and ``measure_candidate`` times its compiled executable.  On the CPU the
     pallas rows run the kernel in interpret mode (flagged per row), so the
     table is regenerable on any host — including CI.
     """
@@ -184,10 +184,11 @@ def best_plan_rows(shapes=JSON_SHAPES) -> dict:
     from repro.core.tuner import (BACKENDS, enumerate_candidates,
                                   measure_candidate, rank_candidates,
                                   resolve_profile)
+    from repro.kernels.ops import interpret_mode
 
     mesh = _make_mesh()
     prof = resolve_profile(TuningCache(path=None), mesh=mesh)
-    interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode()
     rows = []
     for grid in shapes:
         kinds = ("fft",) * len(grid)

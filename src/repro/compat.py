@@ -1,94 +1,40 @@
-"""Version-compatibility shims over the installed jax.
+"""The single home of version-sensitive jax calls (lint rule REP001).
 
-The repo targets the modern jax surface (``jax.shard_map``,
-``jax.sharding.AxisType``, ``jax.make_mesh(..., axis_types=...)``) but must
-also run on jax 0.4.x containers where none of those exist.  Every
-version-sensitive call site goes through this module so the rest of the
-codebase (and the subprocess snippets in tests) stay version-agnostic.
+Every call site whose spelling jax has changed between releases goes
+through this module, so an upgrade touches one file:
 
-Shimmed surface:
-
-* ``AxisType``      — ``jax.sharding.AxisType`` when present, else a small
-  stand-in enum whose ``Auto`` member is accepted by :func:`make_mesh`.
-* ``make_mesh``     — ``jax.make_mesh`` that silently drops ``axis_types``
-  on versions whose signature predates it.
-* ``shard_map``     — ``jax.shard_map`` when present, else
-  ``jax.experimental.shard_map.shard_map`` with the ``check_vma`` kwarg
-  translated to its old spelling ``check_rep``.
+* ``AxisType``      — ``jax.sharding.AxisType``.
+* ``make_mesh``     — ``jax.make_mesh`` with every axis ``AxisType.Auto``
+  unless the caller passes ``axis_types``.  ``jax.make_mesh`` itself
+  defaults to ``Explicit`` axes, under which a plan's
+  ``shard_map``/``NamedSharding`` layouts stop composing with ordinary
+  jnp arithmetic on its outputs.
+* ``shard_map``     — ``jax.shard_map``.
+* ``cost_analysis_dict`` — ``compiled.cost_analysis()`` as a dict.
 """
 from __future__ import annotations
 
-import enum
-import inspect
 from typing import Any, Callable, Optional, Sequence
 
 import jax
-
-try:  # jax >= 0.4.38-ish
-    from jax.sharding import AxisType  # type: ignore[attr-defined]
-
-    _HAS_AXIS_TYPE = True
-except ImportError:  # jax 0.4.37 and older
-
-    class AxisType(enum.Enum):  # type: ignore[no-redef]
-        """Stand-in for ``jax.sharding.AxisType`` on old jax.
-
-        Old meshes are implicitly all-Auto, so accepting and dropping the
-        value preserves semantics.
-        """
-
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-    _HAS_AXIS_TYPE = False
-
-_MAKE_MESH_HAS_AXIS_TYPES = hasattr(jax, "make_mesh") and (
-    "axis_types" in inspect.signature(jax.make_mesh).parameters)
+from jax.sharding import AxisType
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               axis_types: Optional[Sequence[Any]] = None, **kw):
-    """``jax.make_mesh`` across jax versions.
-
-    ``axis_types`` is forwarded when the installed jax understands it and
-    dropped otherwise (old meshes behave as all-Auto already).  Versions
-    predating ``jax.make_mesh`` itself fall back to
-    ``mesh_utils.create_device_mesh``.
-    """
-    if not hasattr(jax, "make_mesh"):
-        from jax.experimental import mesh_utils
-        from jax.sharding import Mesh
-        devices = mesh_utils.create_device_mesh(tuple(axis_shapes))
-        return Mesh(devices, tuple(axis_names))
-    if axis_types is not None and _MAKE_MESH_HAS_AXIS_TYPES and _HAS_AXIS_TYPE:
-        kw["axis_types"] = tuple(axis_types)
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kw)
+    """``jax.make_mesh`` with ``AxisType.Auto`` axes by default."""
+    if axis_types is None:
+        axis_types = (AxisType.Auto,) * len(axis_shapes)
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=tuple(axis_types), **kw)
 
 
-if hasattr(jax, "shard_map"):  # modern spelling
-
-    def shard_map(f: Callable, *, mesh, in_specs, out_specs,
-                  check_vma: bool = True) -> Callable:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-
-else:  # jax 0.4.x: experimental module, kwarg named check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f: Callable, *, mesh, in_specs, out_specs,
-                  check_vma: bool = True) -> Callable:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_vma)
+def shard_map(f: Callable, *, mesh, in_specs, out_specs,
+              check_vma: bool = True) -> Callable:
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a dict on every jax version.
-
-    jax 0.4.x returns a one-element list of properties dicts; newer jax
-    returns the dict directly.  An absent analysis becomes ``{}``.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
+    """``compiled.cost_analysis()``; an absent analysis becomes ``{}``."""
+    return compiled.cost_analysis() or {}

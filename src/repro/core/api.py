@@ -658,6 +658,12 @@ def plan_fft(mesh: Mesh, grid: Sequence[int], *,
                                mode=tuning, cache=tune_cache,
                                default=default, objective=tune_objective)
 
+    if tuned.backend == "pallas":
+        from repro.kernels.ops import unsupported  # deferred: loads Pallas
+        why = unsupported(grid, dtype)
+        if why:
+            raise ValueError(f"plan_fft: backend='pallas' {why}; use "
+                             f"backend='xla' or 'matmul'")
     dec = make_decomposition(tuned.decomp, tuned.mesh_axes, ndim,
                              dim_groups=tuned.dim_groups)
     axis_sizes = dict(zip(mesh.axis_names, mesh.devices.shape))

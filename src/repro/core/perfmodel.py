@@ -39,6 +39,31 @@ TPU_V5E = Machine(name="tpu_v5e", flops=197e12, mem_bw=819e9,
 # paper's overlap wins materialize.
 CPU_CORE = Machine(name="cpu_core", flops=8e9, mem_bw=8e9,
                    net_alpha_s=2e-5, net_bw=0.8e9)
+#: Accelerator constants keyed by ``device_kind`` as JAX reports it.
+#: TPU v5e ("TPU v5 lite"): 197 TFLOP/s bf16 and 819 GB/s HBM per chip
+#: (Google Cloud documentation, "TPU v5e").
+ACCELERATORS = {"TPU v5 lite": TPU_V5E}
+
+
+def default_machine() -> Machine:
+    """Machine constants for the first device of this process.
+
+    The CPU platform gets the calibratable ``CPU_CORE``; an accelerator
+    must be listed in ``ACCELERATORS`` by its ``device_kind`` — an unknown
+    kind is an error, never another chip's constants.
+    """
+    import jax
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return CPU_CORE
+    try:
+        return ACCELERATORS[dev.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no machine constants for {dev.platform} device_kind "
+            f"{dev.device_kind!r}; known kinds: {sorted(ACCELERATORS)} "
+            f"(add it to perfmodel.ACCELERATORS)") from None
+
 
 # Transform kinds -> cost family.  The pruning model prices the three
 # families differently (R2C does half the butterflies; the DCT/DST-II pairs
@@ -177,14 +202,18 @@ def as_profile(machine) -> MachineProfile:
 def _line_flops(n: int, backend: str) -> float:
     """FLOPs of one C2C line of length n — the single source of truth.
 
-    "xla": 5 n log2 n butterflies.  "matmul"/"pallas": the four-step
-    path's two complex matmuls plus twiddle, ~8 real FLOPs per complex MAC
-    over n*(n1+n2) MACs — more raw FLOPs but MXU-shaped, which is what
-    makes the backend an autotuning decision (pallas runs the same
-    algorithm as an explicit kernel, so its flop count is identical; its
-    measured *rate* differs and lives in ``backend_flops``).
+    "xla": 5 n log2 n butterflies.  "matmul": the four-step path's two
+    complex contractions over a balanced split, ~8 real FLOPs per complex
+    MAC over n*(n1+n2) MACs — more raw FLOPs but MXU-shaped, which is what
+    makes the backend an autotuning decision.  "pallas": the kernel's own
+    count (``kernels.ops.line_flops``: MXU matmuls plus VPU tile updates),
+    priced at one blended rate (``backend_flops["pallas"]``, measured by
+    ``calibrate`` at its line length).
     """
-    if backend in ("matmul", "pallas"):
+    if backend == "pallas":
+        from repro.kernels.ops import line_flops  # deferred: loads Pallas
+        return float(sum(line_flops(n)))
+    if backend == "matmul":
         from .transforms import factorize
         n1, n2 = factorize(n)
         return 8.0 * n * (n1 + n2)
@@ -583,7 +612,7 @@ def calibrate(mesh=None, *, n: int = 256, batch: int = 1024,
     timer = timer if timer is not None else time.perf_counter
     platform = platform if platform is not None else jax.default_backend()
     if base is None:
-        base = TPU_V5E if platform == "tpu" else CPU_CORE
+        base = default_machine()
 
     rng = np.random.default_rng(0)
     xc = jnp.asarray((rng.standard_normal((batch, n))

@@ -35,7 +35,6 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import os
 import warnings
 from typing import Callable, List, Optional, Tuple
 
@@ -45,15 +44,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..compat import shard_map
 from . import transforms
-from .decomp import (Decomposition, StageLayout, _as_hop, axis_product,
-                     local_shape)
+from .decomp import Decomposition, StageLayout, axis_product, local_shape
 from .plan import GLOBAL_PLAN_CACHE, plan_key
 from .redistribute import (free_chunk_dim, largest_divisor_at_most,
                            redistribute)
 
 INVERSE_KIND = {"fft": "ifft", "rfft": "irfft", "dct2": "dct3", "dst2": "dst3"}
-# Kinds whose stage line may fuse the pre-hop transpose-pack (pallas only).
-C2C_FUSED_KINDS = ("fft", "ifft")
 # Unnormalized R2R pairs satisfy inv(fwd(x)) = 2N x; complex pairs are
 # self-normalizing through jnp conventions.
 R2R_INV_SCALE = {"dct3", "dst3"}
@@ -289,59 +285,10 @@ def make_spec(mesh: Mesh, grid: Tuple[int, ...], decomp: Decomposition,
     return dataclasses.replace(spec, chunk_schedule=clamped)
 
 
-def _pallas_fuse_enabled() -> bool:
-    """Env toggle for the pallas pack-fusion epilogue (default on).
-
-    ``REPRO_PALLAS_FUSE=0`` forces the unfused path — the fused-vs-unfused
-    identity tests flip this to compare the two pipelines bit-for-bit.
-    """
-    return os.environ.get("REPRO_PALLAS_FUSE", "1").lower() \
-        not in ("0", "off", "false")
-
-
-def _pack_fusion_site(spec: PipelineSpec, stage: StageLayout,
-                      next_hop) -> Tuple[Optional[int], Optional[str]]:
-    """Static decision: which of this stage's dims (if any) can fuse the
-    pre-``RedistHop`` transpose-pack into the pallas kernel's epilogue.
-
-    Fusable when the *last-executed* C2C line of the stage transforms the
-    very dim the following hop's first ``all_to_all`` splits: the kernel
-    then stores its output pre-split into the per-destination blocks the
-    collective ships, saving the separate pack pass.  Returns
-    ``(spatial_dim, mesh_axis)`` or ``(None, None)``.
-    """
-    if spec.backend != "pallas" or next_hop is None \
-            or not _pallas_fuse_enabled():
-        return None, None
-    dims = stage.fft_dims if not spec.inverse else stage.fft_dims[::-1]
-    if not dims:
-        return None, None
-    d_last = dims[-1]
-    kind = spec.kinds[d_last]
-    if spec.inverse:
-        kind = INVERSE_KIND[kind]
-    if kind not in ("fft", "ifft"):
-        # rfft pads and R2R rescales *after* the transform — the packed
-        # store would not be the layout the collective ships.  Bail out.
-        return None, None
-    mv = _as_hop(next_hop).moves[0]
-    if mv.split_dim != d_last:
-        return None, None
-    return d_last, mv.mesh_axis
-
-
 def _stage_transform(spec: PipelineSpec, stage: StageLayout,
-                     is_first: bool, is_last: bool,
-                     next_hop=None, axis_sizes=None) -> Callable:
-    """Local transform for one stage (may cover 2 dims for slabs).
-
-    ``next_hop``/``axis_sizes`` feed the pallas pack-fusion epilogue: when
-    the stage's last C2C line transforms the dim the following hop splits,
-    the kernel stores it pre-packed for that hop's first all_to_all.
-    """
+                     is_first: bool, is_last: bool) -> Callable:
+    """Local transform for one stage (may cover 2 dims for slabs)."""
     off = spec.spatial_offset
-    fuse_dim, fuse_axis = (None, None) if axis_sizes is None else \
-        _pack_fusion_site(spec, stage, next_hop)
 
     def run(x: jax.Array) -> jax.Array:
         dims = stage.fft_dims if not spec.inverse else stage.fft_dims[::-1]
@@ -349,16 +296,6 @@ def _stage_transform(spec: PipelineSpec, stage: StageLayout,
             kind = spec.kinds[d]
             if spec.inverse:
                 kind = INVERSE_KIND[kind]
-            if kind in C2C_FUSED_KINDS and d == fuse_dim:
-                parts = axis_sizes[fuse_axis]
-                if parts > 1 and x.shape[d + off] % parts == 0:
-                    # Fused epilogue: the kernel's final store writes the
-                    # transformed dim pre-split into the ``parts``
-                    # contiguous blocks the next all_to_all sends.
-                    from repro.kernels import ops
-                    fn = ops.ifft1d if kind == "ifft" else ops.fft1d
-                    x = fn(x, d + off, pack_parts=parts)
-                    continue
             if kind == "irfft":
                 # trim the frequency pad, then invert to the real length
                 nfreq = spec.grid[0] // 2 + 1
@@ -381,21 +318,17 @@ def _stage_transform(spec: PipelineSpec, stage: StageLayout,
     return run
 
 
-def _local_pipeline(spec: PipelineSpec, axis_sizes=None) -> Callable:
+def _local_pipeline(spec: PipelineSpec) -> Callable:
     """The per-device function to be shard_map'd."""
     stages, redists = spec.stage_order()
 
     def run(x: jax.Array) -> jax.Array:
         off = spec.spatial_offset
-        x = _stage_transform(spec, stages[0], True, len(stages) == 1,
-                             next_hop=redists[0] if redists else None,
-                             axis_sizes=axis_sizes)(x)
+        x = _stage_transform(spec, stages[0], True, len(stages) == 1)(x)
         for i, hop in enumerate(redists):
             nxt_stage = stages[i + 1]
-            nxt_hop = redists[i + 1] if i + 1 < len(redists) else None
             nxt = _stage_transform(spec, nxt_stage, False,
-                                   i + 1 == len(stages) - 1,
-                                   next_hop=nxt_hop, axis_sizes=axis_sizes)
+                                   i + 1 == len(stages) - 1)
             # The chunk dim must dodge the fused transform's dims, or the
             # per-chunk FFT would run over a split dim (the inverse-slab
             # bug); redistribute falls back to bulk when none is legal.
@@ -412,8 +345,7 @@ def _local_pipeline(spec: PipelineSpec, axis_sizes=None) -> Callable:
 
 def build_pipeline(mesh: Mesh, spec: PipelineSpec) -> Callable:
     """shard_map the local pipeline over the mesh.  jit-compatible."""
-    axis_sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-    fn = shard_map(_local_pipeline(spec, axis_sizes), mesh=mesh,
+    fn = shard_map(_local_pipeline(spec), mesh=mesh,
                    in_specs=spec.in_spec(), out_specs=spec.out_spec(),
                    check_vma=False)
     return fn
@@ -499,12 +431,8 @@ def compile_pipeline(mesh: Mesh, spec: PipelineSpec,
 # Segment 0 applies the stage-0 local transform; segment j (1-based) applies
 # hop j-1's redistribution — at that hop's own chunk_schedule entry — with
 # stage j's transform fused per chunk, exactly like the monolithic
-# _local_pipeline's loop body.  The only intentional divergence is the
-# pallas pack-fusion epilogue: a stage's epilogue packs for the *next* hop,
-# which lives in the next segment's executable, so segments always build
-# their stage transform with next_hop=None (fused-vs-unfused is bitwise
-# identical, so chained segments still match the monolithic pipeline
-# bit for bit).
+# _local_pipeline's loop body, so chained segments match the monolithic
+# pipeline bit for bit.
 
 
 def n_segments(spec: PipelineSpec) -> int:
@@ -533,7 +461,7 @@ def segment_out_spec(spec: PipelineSpec, index: int) -> P:
     return P(*(spec.batch_spec + stages[index].spec))
 
 
-def _local_segment(spec: PipelineSpec, index: int, axis_sizes=None) -> Callable:
+def _local_segment(spec: PipelineSpec, index: int) -> Callable:
     """The per-device function of one stage segment (to be shard_map'd)."""
     stages, redists = spec.stage_order()
     if not 0 <= index < len(stages):
@@ -541,8 +469,7 @@ def _local_segment(spec: PipelineSpec, index: int, axis_sizes=None) -> Callable:
                          f"{len(stages)} stages")
     off = spec.spatial_offset
     last = index == len(stages) - 1
-    stage_fn = _stage_transform(spec, stages[index], index == 0, last,
-                                next_hop=None, axis_sizes=axis_sizes)
+    stage_fn = _stage_transform(spec, stages[index], index == 0, last)
     if index == 0:
         return stage_fn
     hop = redists[index - 1]
@@ -558,8 +485,7 @@ def _local_segment(spec: PipelineSpec, index: int, axis_sizes=None) -> Callable:
 
 def build_segment(mesh: Mesh, spec: PipelineSpec, index: int) -> Callable:
     """shard_map one stage segment over the mesh.  jit-compatible."""
-    axis_sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-    return shard_map(_local_segment(spec, index, axis_sizes), mesh=mesh,
+    return shard_map(_local_segment(spec, index), mesh=mesh,
                      in_specs=segment_in_spec(spec, index),
                      out_specs=segment_out_spec(spec, index),
                      check_vma=False)

@@ -9,9 +9,12 @@ Three interchangeable backends (``LOCAL_BACKENDS``):
   separate real/imag planes.  This is the TPU-native formulation (MXU work
   instead of VPU butterflies) expressed as pure jnp ops.
 * ``"pallas"`` — the same four-step algorithm as an explicit Pallas kernel
-  (``kernels/fft_matmul.py``, wrapped by ``kernels/ops.py``), with fused
-  epilogues for the DCT phase twiddle and the pre-redistribution
-  transpose-pack.  Off-TPU it runs in interpret mode so tests stay hermetic.
+  (``kernels/fft_matmul.py``, wrapped by ``kernels/ops.py``), with the DCT
+  phase twiddle fused into its matmul operand.  On the CPU platform it runs
+  in interpret mode so tests stay hermetic.
+
+The matmul and pallas backends contract at ``MATMUL_PRECISION``
+(``kernels/precision.py``).
 
 R2C/R2R transforms are composed from the complex FFT with the standard
 even/odd reordering identities, so they inherit whichever backend is
@@ -29,6 +32,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.kernels.precision import MATMUL_PRECISION
 
 C2C_KINDS = ("fft", "ifft")
 R2C_KINDS = ("rfft", "irfft")
@@ -76,30 +81,36 @@ def _twiddle_planes(n1: int, n2: int, sign: float, dtype: str):
     return (np.cos(theta).astype(dtype), np.sin(theta).astype(dtype))
 
 
-def _cmatmul(ar, ai, br, bi, *, side: str):
+def _cmatmul(ar, ai, br, bi, *, side: str, precision=MATMUL_PRECISION):
     """Complex matmul via 4 real matmuls on (..., rows, cols) planes.
 
     side="left":  result = B @ A   (contract A's rows with B's cols)
     side="right": result = A @ B
     """
+    def mm(spec, u, v):
+        return jnp.einsum(spec, u, v, precision=precision)
+
     if side == "left":
-        rr = jnp.einsum("kn,...nm->...km", br, ar)
-        ri = jnp.einsum("kn,...nm->...km", br, ai)
-        ir = jnp.einsum("kn,...nm->...km", bi, ar)
-        ii = jnp.einsum("kn,...nm->...km", bi, ai)
+        rr = mm("kn,...nm->...km", br, ar)
+        ri = mm("kn,...nm->...km", br, ai)
+        ir = mm("kn,...nm->...km", bi, ar)
+        ii = mm("kn,...nm->...km", bi, ai)
     else:
-        rr = jnp.einsum("...kn,nm->...km", ar, br)
-        ri = jnp.einsum("...kn,nm->...km", ar, bi)
-        ir = jnp.einsum("...kn,nm->...km", ai, br)
-        ii = jnp.einsum("...kn,nm->...km", ai, bi)
+        rr = mm("...kn,nm->...km", ar, br)
+        ri = mm("...kn,nm->...km", ar, bi)
+        ir = mm("...kn,nm->...km", ai, br)
+        ii = mm("...kn,nm->...km", ai, bi)
     return rr - ii, ri + ir
 
 
-def fourstep_fft_planes(xr, xi, *, inverse: bool = False):
+def fourstep_fft_planes(xr, xi, *, inverse: bool = False,
+                        precision=MATMUL_PRECISION):
     """Four-step FFT along the last axis of real/imag planes (..., N).
 
     X[k1 + N1*k2] = sum_{m2} W_N2^{m2 k2} [ W_N^{m2 k1}
                         sum_{m1} x[m1*N2 + m2] W_N1^{m1 k1} ]
+
+    ``precision`` is the contractions' ``jax.lax.Precision``.
     """
     n = xr.shape[-1]
     n1, n2 = factorize(n)
@@ -115,12 +126,14 @@ def fourstep_fft_planes(xr, xi, *, inverse: bool = False):
     xi = xi.reshape(xi.shape[:-1] + (n1, n2))
 
     # step 1: DFT_N1 over m1 (left-multiply) -> F1[k1, m2]
-    f1r, f1i = _cmatmul(xr, xi, jnp.asarray(w1r), jnp.asarray(w1i), side="left")
+    f1r, f1i = _cmatmul(xr, xi, jnp.asarray(w1r), jnp.asarray(w1i),
+                        side="left", precision=precision)
     # step 2: fused twiddle W_N^{k1 m2}
     g_r = f1r * tr - f1i * ti
     g_i = f1r * ti + f1i * tr
     # step 3: DFT_N2 over m2 (right-multiply, W2 symmetric) -> F2[k1, k2]
-    f2r, f2i = _cmatmul(g_r, g_i, jnp.asarray(w2r), jnp.asarray(w2i), side="right")
+    f2r, f2i = _cmatmul(g_r, g_i, jnp.asarray(w2r), jnp.asarray(w2i),
+                        side="right", precision=precision)
     # step 4: X[k1 + N1*k2]  ->  layout [k2, k1], then flatten
     outr = jnp.swapaxes(f2r, -1, -2).reshape(xr.shape[:-2] + (n,))
     outi = jnp.swapaxes(f2i, -1, -2).reshape(xi.shape[:-2] + (n,))
@@ -148,8 +161,7 @@ def _c2c(x: jax.Array, axis: int, *, inverse: bool, backend: str) -> jax.Array:
     if backend == "xla":
         return (jnp.fft.ifft if inverse else jnp.fft.fft)(x, axis=axis)
     if backend == "pallas":
-        # Deferred import: kernels/fft_matmul.py imports ``factorize`` from
-        # this module, so a top-level import here would be circular.
+        # Deferred import: the kernel module loads Pallas.
         from repro.kernels import ops
         return (ops.ifft1d if inverse else ops.fft1d)(x, axis)
     if backend != "matmul":

@@ -18,7 +18,9 @@ runtime should choose.  ``tune()`` closes that loop for one problem key
 3. **measure** each survivor's compiled executable (the measurement also
    warms the in-process `PlanCache`, so the winning plan is free to call
    afterwards), always including the static default as the baseline so the
-   winner can never regress it;
+   winner can never regress it.  A candidate whose compiled footprint does
+   not fit a device's memory (``fits_device``) is passed over before it
+   runs, and the next-ranked one takes its place;
 4. **record** the winner in a persistent JSON `TuningCache` keyed by the
    problem, the FFTW-wisdom analogue — later processes skip straight to 4.
 
@@ -57,9 +59,9 @@ import numpy as np
 from jax.sharding import Mesh
 
 from .decomp import describe_decomp, make_decomposition, validate_grid
-from .perfmodel import (CPU_CORE, TPU_V5E, Machine, MachineProfile,
-                        _calibrate_network, _time_best, calibrate,
-                        hop_cost_terms, predict_plan_time,
+from .perfmodel import (MachineProfile, _calibrate_network,
+                        _time_best, calibrate, hop_cost_terms,
+                        default_machine, predict_plan_time,
                         profile_from_machine)
 from .pipeline import (PipelineSpec, chunk_sites, compile_pipeline,
                        effective_grid, input_struct, make_spec,
@@ -70,9 +72,17 @@ from .scheduler import choose_chunk_schedule
 
 # The tuner's full backend space — mirrors ``transforms.LOCAL_BACKENDS``.
 # "pallas" is the explicit MXU kernel (kernels/fft_matmul.py) with fused
-# twiddle/pack epilogues; off-TPU it runs in interpret mode.
+# DCT twiddle; on the CPU platform it runs in interpret mode.
 BACKENDS = ("xla", "matmul", "pallas")
 OBJECTIVES = ("forward", "fwd+scale+inv")
+# Deepest chunk count per hop a model-only choice makes (heuristic tuning,
+# ``propose_chunk_schedule``).  The cost model has no memory term, and the
+# chunk chains are independent, so XLA keeps more of them live the deeper
+# the schedule: compiled for a v5e 2x2 mesh, a 1024^3 complex64 pencil
+# needs 4 GiB of temporaries per chip at 8 chunks per hop, 7.5 GiB at 16,
+# and does not fit the 16 GiB chip at 64.  Auto tuning enumerates deeper
+# counts too and drops those that do not fit once compiled.
+MODEL_MAX_CHUNKS = 16
 
 
 @dataclasses.dataclass(frozen=True)
@@ -100,11 +110,6 @@ class Candidate:
                   if self.chunk_schedule is not None else str(self.n_chunks))
         return (f"{decomp}({','.join(self.mesh_axes)})/"
                 f"{self.backend}/chunks={chunks}")
-
-
-def default_machine() -> Machine:
-    """Machine constants for the pruning model, matched to the runtime."""
-    return TPU_V5E if jax.default_backend() == "tpu" else CPU_CORE
 
 
 # (platform, mesh-axis name) pairs whose all_to_all terms this process
@@ -201,8 +206,8 @@ def feasible_chunk_counts(spec: PipelineSpec, axis_sizes: Dict[str, int],
     For each redistribution the chunk dim is the one ``redistribute`` will
     pick (``pipeline.chunk_sites`` — which dodges the hop's exchange dims
     *and* the downstream stage's fft_dims); ``n_chunks`` must divide its
-    local size at that stage.  Returns at least ``[1]`` (the bulk path is
-    always feasible).
+    local size at that stage (and be at most ``max_chunks``, if given).
+    Returns at least ``[1]`` (the bulk path is always feasible).
     """
     sizes = []
     for d, size in chunk_sites(spec, axis_sizes):
@@ -269,14 +274,17 @@ def propose_chunk_schedule(spec: PipelineSpec, axis_sizes: Dict[str, int],
     kind-aware per-stage FFT costs (``perfmodel.hop_cost_terms``) into
     ``scheduler.choose_chunk_schedule`` (Eq. 7 argmin per hop), restricted
     to each hop's feasible counts (``feasible_hop_chunk_counts``, i.e. the
-    ``chunk_sites`` clamp).  Returns the schedule in **forward hop order**
+    ``chunk_sites`` clamp) up to ``max_chunks`` (default
+    ``MODEL_MAX_CHUNKS``: the model cannot see memory).  Returns the
+    schedule in **forward hop order**
     (what ``make_spec`` and ``Candidate.chunk_schedule`` expect), whatever
     the spec's direction.
     """
     from .perfmodel import as_profile, stage_comp_times
     prof = as_profile(machine)
-    cands = feasible_hop_chunk_counts(spec, axis_sizes, batch_shape,
-                                      max_chunks)
+    cands = feasible_hop_chunk_counts(
+        spec, axis_sizes, batch_shape,
+        MODEL_MAX_CHUNKS if max_chunks is None else max_chunks)
     stage_t = stage_comp_times(spec.grid, spec.decomp, axis_sizes, prof,
                                backend=backend or spec.backend,
                                dtype_bytes=dtype_bytes, kinds=spec.kinds,
@@ -497,6 +505,44 @@ def measure_candidate(cand: Candidate, grid: Tuple[int, ...], mesh: Mesh,
     return _time_best(lambda: exe(x), time.perf_counter, repeats)
 
 
+def device_memory_limit(mesh: Mesh) -> Optional[int]:
+    """The smallest memory limit among the mesh's devices, as the runtime
+    reports it (``memory_stats()["bytes_limit"]``), or ``None`` where a
+    device reports none (the CPU platform)."""
+    limits = []
+    for dev in mesh.devices.flat:
+        stats = dev.memory_stats()
+        if not stats or "bytes_limit" not in stats:
+            return None
+        limits.append(int(stats["bytes_limit"]))
+    return min(limits)
+
+
+def fits_device(cand: Candidate, grid: Tuple[int, ...], mesh: Mesh,
+                kinds: Tuple[str, ...], dtype, limit: int, *,
+                inverse: bool = False, batch_shape: Tuple[int, ...] = (),
+                objective: str = "forward") -> bool:
+    """Whether each executable the candidate runs fits ``limit`` bytes per
+    device: arguments, output and temporaries of the compiled program
+    (``memory_analysis``).  Compiles through the plan cache, so a
+    following :func:`measure_candidate` reuses the executables."""
+    directions = [inverse] if objective == "forward" else [inverse,
+                                                           not inverse]
+    dt = dtype
+    for direction in directions:
+        spec = _spec_for(mesh, grid, cand.decomp, cand.mesh_axes, kinds,
+                         cand.backend, cand.spec_chunks, direction,
+                         len(batch_shape), dim_groups=cand.dim_groups)
+        mem = compile_pipeline(mesh, spec, batch_shape=batch_shape,
+                               dtype=dt).memory_analysis()
+        used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes)
+        if used > limit:
+            return False
+        dt = output_struct(mesh, spec, batch_shape, dt).dtype
+    return True
+
+
 def _default_candidate(cands: Sequence[Candidate]) -> Optional[Candidate]:
     """The plan the static API would have used (baseline to never regress)."""
     for cand in cands:
@@ -579,6 +625,11 @@ def tune(grid: Sequence[int], mesh: Mesh, *,
         raise ValueError(f"tune objective must be one of {OBJECTIVES}, "
                          f"got {objective!r}")
     unrestricted = set(BACKENDS).issubset(set(backends)) and max_chunks is None
+    from repro.kernels.ops import unsupported  # deferred: loads Pallas
+    if unsupported(grid, dtype):
+        # The kernel cannot run these lines here (f64 on a chip, or lines
+        # too long for its VMEM): not a candidate.
+        backends = tuple(b for b in backends if b != "pallas")
 
     key = tuning_key(grid=grid, mesh_shape=tuple(mesh.devices.shape),
                      mesh_axes=tuple(mesh.axis_names), kinds=kinds,
@@ -607,6 +658,8 @@ def tune(grid: Sequence[int], mesh: Mesh, *,
         machine = resolve_profile(profile_cache, mesh=mesh,
                                   allow_calibrate=(mode == "auto"))
     dtype_bytes = jnp.dtype(dtype).itemsize
+    if max_chunks is None and mode == "heuristic":
+        max_chunks = MODEL_MAX_CHUNKS  # the model alone cannot see memory
     cands = enumerate_candidates(grid, mesh, kinds, inverse=inverse,
                                  n_batch=len(batch_shape),
                                  batch_shape=batch_shape, backends=backends,
@@ -628,10 +681,27 @@ def tune(grid: Sequence[int], mesh: Mesh, *,
                          chunk_schedule=best.chunk_schedule,
                          objective=objective)
 
-    survivors = [c for _, c in ranked[:max(top_k, 1)]]
+    limit = device_memory_limit(mesh)
+
+    def fits(cand: Candidate) -> bool:
+        return limit is None or fits_device(
+            cand, grid, mesh, kinds, dtype, limit, inverse=inverse,
+            batch_shape=batch_shape, objective=objective)
+
+    # The top_k best-ranked candidates that fit, in rank order.
+    survivors: List[Candidate] = []
+    for _, cand in ranked:
+        if len(survivors) == max(top_k, 1):
+            break
+        if fits(cand):
+            survivors.append(cand)
     baseline = _default_candidate(cands)
-    if baseline is not None and baseline not in survivors:
+    if baseline is not None and baseline not in survivors and fits(baseline):
         survivors.append(baseline)
+    if not survivors:
+        raise ValueError(
+            f"no plan for grid {grid} fits the {limit} bytes of device "
+            f"memory on mesh {dict(zip(mesh.axis_names, mesh.devices.shape))}")
     predicted = {c: p for p, c in ranked}
     best_cand, best_time, baseline_time = None, float("inf"), 0.0
     for cand in survivors:

@@ -1,4 +1,4 @@
-"""Pallas TPU kernel: batched 1D FFT as four-step MXU matmuls.
+"""Pallas TPU kernel: batched 1D FFT as a four-step VPU/MXU transform.
 
 The paper's local transforms call FFTW/cuFFT; TPU has neither, and a
 butterfly network is VPU-bound.  The TPU-native formulation factorizes
@@ -7,42 +7,44 @@ N = N1*N2 and evaluates
     X[k1 + N1*k2] = sum_{m2} W_N2^{m2 k2} * W_N^{m2 k1}
                         * sum_{m1} x[m1*N2 + m2] * W_N1^{m1 k1}
 
-as two dense DFT-matrix contractions (MXU) with a fused elementwise twiddle
-(VPU), on separate real/imag planes (no complex datapath on the MXU).
+on separate real/imag planes (no complex datapath on the MXU).
 
-This is the kernel behind ``backend="pallas"`` — the tuner's third local-FFT
-backend (``kernels/ops.py`` wraps it; ``core/transforms.apply_1d`` routes to
-it).  Two **fused epilogues** extend the basic transform so a pipeline stage
-can finish inside the kernel instead of paying another memory round trip
-over the array:
+**Layout.**  The kernel transforms axis 1 of ``(G, N, M)`` planes: the
+transformed index runs down the sublanes and the ``M`` independent lines
+across the lanes, so every view the algorithm needs is a row slice:
 
-* ``twiddle=(er, ei)`` — an extra elementwise complex multiply along the
-  output axis, applied in-register after step 4.  The DCT-II phase factor
-  (``transforms._dct2``) rides here, so an R2R stage's post-FFT phase pass
-  never touches HBM separately.
-* ``pack_parts=p`` — the transpose-pack that precedes a ``RedistHop``:
-  the final store writes the output pre-split into ``p`` contiguous
-  per-destination blocks, shape ``(B, p, N//p)`` — exactly the layout the
-  following ``lax.all_to_all(tiled=True)`` ships, so the pack pass between
-  a stage's FFT and its redistribution folds into the kernel's epilogue.
+* the input rows of one ``m1`` are the contiguous block ``[m1*N2, (m1+1)*N2)``;
+* the output rows of one ``k1`` are ``k1, k1 + N1, ...`` — one strided store.
 
-Layout: the batch dim is tiled over the grid; each program loads a
-(TB, N1, N2) block of both planes into VMEM together with the small
-constant operands (W1: N1xN1, W2: N2xN2, T: N1xN2, optional epilogue
-twiddle 1xN — broadcast to every program via a constant index_map).  The
-chunked-overlap pipeline feeds the same kernel per-chunk ``(TB, N1, N2)``
-blocks — a chunk is just a smaller batch, re-tiled by ``batch_tile``.
+No array changes shape inside the kernel (Mosaic rejects lane/sublane shape
+casts), and any axis of a row-major array is such a ``(G, N, M)`` view for
+free: only a last-axis transform needs a transpose (``kernels/ops.py``).
 
-Precision follows the input planes: float32 planes contract in f32 (the
-MXU path); float64 planes (an x64 pipeline) build the DFT/twiddle operands
-in f64 and accumulate in f64 — supported in ``interpret`` mode and on
-backends with an f64 datapath; real MXUs run the f32 variant.
+**Steps.**  N2 is the MXU contraction (the largest divisor of N up to 128,
+``factors``); N1 = N / N2 is small.
 
-VMEM budget per program (f32): 2*TB*N (in) + 2*TB*N (out) + 2*TB*N (scratch
-peak) + matrices ~= 6*TB*N*4 bytes; TB=128, N=1024 -> ~3.1 MiB, comfortably
-inside the ~16 MiB/core of v5e.  The MXU sees contraction dims N1, N2
-(balanced ~sqrt(N)); for N >= 16384 prefer recursing the four-step instead
-of letting N2 exceed 128.
+1. ``F_k1 = sum_m1 W1[k1, m1] * x_m1`` — N1 x N1 scalar-times-tile
+   accumulations on the VPU (the W1 entries are compile-time constants).
+2. ``X_k1 = M_k1 @ F_k1`` — one complex (N2 x N2) @ (N2 x lanes) matmul per
+   ``k1`` on the MXU, stored to the strided output rows of ``k1``.
+
+``M_k1[k2, m2] = e[k1 + N1*k2] * W_N2^{m2 k2} * W_N^{m2 k1} * s`` folds the
+four-step twiddle, the optional output twiddle ``e`` (the DCT-II phase of
+``transforms._dct2``) and the inverse's 1/N into the matmul operand, so
+neither epilogue costs a pass over the data.
+
+**Precision.**  f32 contractions use ``MATMUL_PRECISION``
+(``kernels/precision.py``, ``HIGHEST``: the MXU's f32 path); the default
+single bf16 pass misses the transform's 1e-4 tolerance on TPU.  f64 planes
+(x64 pipelines) run only in interpret mode — Mosaic has no f64, and the
+compiled path refuses them with a ``ValueError``.
+
+**Limits.**  One program holds four ``(N, 128)`` line blocks and the
+``(N1, N2, N2)`` operand, each double-buffered, in VMEM (``vmem_bytes``).
+Lines whose program does not fit Mosaic's default scoped VMEM
+(``VMEM_LIMIT``) are refused on every platform (``supports_length``):
+powers of two up to N = 2048, no N above 4096, and prime N only up to
+about 900 (the dense ``(1, N)`` split).
 """
 from __future__ import annotations
 
@@ -54,174 +56,160 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from repro.core.transforms import factorize
+from .precision import MATMUL_PRECISION
 
-DEFAULT_BATCH_TILE = 128
+#: The MXU contraction the kernel aims for (one 128 x 128 systolic pass),
+#: and its lane tile: Mosaic's strided store needs a 128-lane block.
+MXU_DIM = 128
+#: Mosaic's default scoped-VMEM limit on TPU v5e: one program's blocks must
+#: fit it (compiled for v5e, N = 4096 runs out of VMEM; N = 2048 fits).
+VMEM_LIMIT = 16 * 2**20
 
 
-def _planes(n1: int, n2: int, inverse: bool,
-            dtype: str = "float32") -> Tuple[np.ndarray, ...]:
-    """Constant operands: DFT(N1), DFT(N2) and the twiddle, as cos/sin planes.
+def factors(n: int) -> Tuple[int, int]:
+    """The kernel's split N = N1*N2, N2 the MXU contraction.
 
-    Built in float64 and cast to ``dtype`` so f32 runs see a well-rounded
-    operand; x64 pipelines keep the full double-precision phases.
+    N2 is the largest divisor of N not above ``MXU_DIM``.  Lines that have
+    no such divisor at least as large as N1 (e.g. a prime N) run as one
+    dense DFT matmul, ``(1, N)``; ``supports_length`` bounds how large.
     """
+    if n <= MXU_DIM:
+        return 1, n
+    n2 = max(d for d in range(1, MXU_DIM + 1) if n % d == 0)
+    if n // n2 > n2:
+        return 1, n
+    return n // n2, n2
+
+
+def vmem_bytes(n: int) -> int:
+    """VMEM one kernel program holds for lines of length ``n`` (f32): the
+    four ``(N, 128)`` line blocks and the real/imag ``(N1, N2, N2)``
+    operand, each double-buffered."""
+    n1, n2 = factors(n)
+    return 2 * 4 * (4 * n * MXU_DIM + 2 * n1 * n2 * n2)
+
+
+def supports_length(n: int) -> bool:
+    """Whether the kernel takes lines of length ``n``: its program fits
+    ``VMEM_LIMIT``.  Checked on every platform, so interpret mode refuses
+    what the compiled kernel would (and never builds an N x N operand)."""
+    return n >= 1 and vmem_bytes(n) <= VMEM_LIMIT
+
+
+def line_flops(n: int) -> Tuple[float, float]:
+    """Real FLOPs per line of length ``n``, as ``(mxu, vpu)``.
+
+    The MXU runs N1 complex ``(N2 x N2) @ F`` products (four real matmuls
+    each); the VPU runs step 1's scalar-times-tile updates (zero constants
+    skipped) and the real/imag combines of step 2.
+    """
+    n1, n2 = factors(n)
+    terms = sum(bool(c) + bool(s) for row in _w1_constants(n1, -1.0)
+                for c, s in row)
+    return 8.0 * n * n2, 4.0 * n2 * terms + 2.0 * n
+
+
+def _phase(sign: float, num: np.ndarray, n: int) -> np.ndarray:
+    """exp(sign * 2 pi i * num / n) in complex128."""
+    return np.exp((sign * 2j * np.pi / n) * num)
+
+
+def _w1_constants(n1: int, sign: float):
+    """W_N1^{k1 m1} as python (cos, sin) pairs; exact zeros are kept exact
+    so that step 1 skips them (N1 = 2, 4 are pure sign flips)."""
+    w = np.round(_phase(sign, np.outer(np.arange(n1), np.arange(n1)), n1),
+                 15)
+    return tuple(tuple((float(v.real), float(v.imag)) for v in row)
+                 for row in w)
+
+
+def _operand(n1: int, n2: int, inverse: bool) -> np.ndarray:
+    """The static part of ``M`` (see the module docstring), (N1, N2, N2)."""
     n = n1 * n2
     sign = 1.0 if inverse else -1.0
-    j1 = np.arange(n1, dtype=np.float64)
-    j2 = np.arange(n2, dtype=np.float64)
-    th1 = (sign * 2 * np.pi / n1) * np.outer(j1, j1)
-    th2 = (sign * 2 * np.pi / n2) * np.outer(j2, j2)
-    tht = (sign * 2 * np.pi / n) * np.outer(j1, j2)
-    return (np.cos(th1).astype(dtype), np.sin(th1).astype(dtype),
-            np.cos(th2).astype(dtype), np.sin(th2).astype(dtype),
-            np.cos(tht).astype(dtype), np.sin(tht).astype(dtype))
+    k1 = np.arange(n1)[:, None, None]
+    k2 = np.arange(n2)[None, :, None]
+    m2 = np.arange(n2)[None, None, :]
+    m = _phase(sign, k2 * m2, n2) * _phase(sign, k1 * m2, n)
+    return m / n if inverse else m
 
 
-def _fft_kernel(*refs, n1: int, n2: int, inverse: bool,
-                fused_twiddle: bool, pack_parts: Optional[int]):
-    if fused_twiddle:
-        (xr_ref, xi_ref, w1r_ref, w1i_ref, w2r_ref, w2i_ref,
-         tr_ref, ti_ref, er_ref, ei_ref, outr_ref, outi_ref) = refs
-    else:
-        (xr_ref, xi_ref, w1r_ref, w1i_ref, w2r_ref, w2i_ref,
-         tr_ref, ti_ref, outr_ref, outi_ref) = refs
-        er_ref = ei_ref = None
-    tb = xr_ref.shape[0]
-    n = n1 * n2
+def _fft_kernel(xr_ref, xi_ref, mr_ref, mi_ref, outr_ref, outi_ref, *,
+                n1: int, n2: int, w1, precision):
     acc = xr_ref.dtype  # f32 planes accumulate in f32, f64 (x64) in f64
-    xr = xr_ref[...].reshape(tb, n1, n2)
-    xi = xi_ref[...].reshape(tb, n1, n2)
-    w1r, w1i = w1r_ref[...], w1i_ref[...]
-    w2r, w2i = w2r_ref[...], w2i_ref[...]
-    tr, ti = tr_ref[...], ti_ref[...]
 
-    dn = (((1,), (1,)), ((), ()))  # contract x dim 1 (m1) with W1 dim 1
+    def dot(a, b):
+        return jax.lax.dot(a, b, precision=precision,
+                           preferred_element_type=acc)
 
-    def dot1(a, w):  # (tb, n1, n2) x (n1, n1) -> (tb, n2, k1)
-        return jax.lax.dot_general(a, w, dimension_numbers=dn,
-                                   preferred_element_type=acc)
-
-    # step 1: F1[b, m2, k1] = sum_m1 x[b, m1, m2] W1[k1, m1]
-    f1r = dot1(xr, w1r) - dot1(xi, w1i)
-    f1i = dot1(xr, w1i) + dot1(xi, w1r)
-
-    # step 2: fused twiddle T[k1, m2] -> broadcast as [1, m2, k1]
-    t_r = tr.T[None]
-    t_i = ti.T[None]
-    g_r = f1r * t_r - f1i * t_i
-    g_i = f1r * t_i + f1i * t_r
-
-    # step 3: F2[b, k1, k2] = sum_m2 G[b, m2, k1] W2[k2, m2]
-    def dot2(a, w):  # (tb, n2, n1) x (n2, n2) -> (tb, n1, k2)
-        return jax.lax.dot_general(a, w, dimension_numbers=dn,
-                                   preferred_element_type=acc)
-
-    f2r = dot2(g_r, w2r) - dot2(g_i, w2i)
-    f2i = dot2(g_r, w2i) + dot2(g_i, w2r)
-
-    # step 4: X[k1 + N1*k2] -> row-major layout [k2, k1]
-    outr = jnp.swapaxes(f2r, 1, 2).reshape(tb, n)
-    outi = jnp.swapaxes(f2i, 1, 2).reshape(tb, n)
-    if fused_twiddle:
-        # epilogue A: extra elementwise complex multiply along the output
-        # axis (e.g. the DCT-II phase), in-register — no extra HBM pass.
-        er, ei = er_ref[...], ei_ref[...]  # (1, n), broadcast over batch
-        outr, outi = outr * er - outi * ei, outr * ei + outi * er
-    if inverse:
-        outr = outr * (1.0 / n)
-        outi = outi * (1.0 / n)
-    if pack_parts is not None:
-        # epilogue B: transpose-pack — store the output pre-split into the
-        # contiguous per-destination blocks the next all_to_all sends.
-        outr_ref[...] = outr.reshape(tb, pack_parts, n // pack_parts)
-        outi_ref[...] = outi.reshape(tb, pack_parts, n // pack_parts)
-    else:
-        outr_ref[...] = outr
-        outi_ref[...] = outi
+    for k1 in range(n1):
+        # step 1 (VPU): F = sum_m1 W1[k1, m1] x_m1, constants inlined
+        fr = fi = 0.0
+        for m1, (c, s) in enumerate(w1[k1]):
+            rows_in = pl.ds(m1 * n2, n2)
+            ar, ai = xr_ref[rows_in, :], xi_ref[rows_in, :]
+            if c:
+                fr, fi = fr + c * ar, fi + c * ai
+            if s:
+                fr, fi = fr - s * ai, fi + s * ar
+        # step 2 (MXU): X rows k1, k1 + N1, ... = M_k1 @ F
+        mr, mi = mr_ref[k1], mi_ref[k1]
+        rows = pl.ds(k1, n2, stride=n1) if n1 > 1 else pl.ds(0, n2)
+        outr_ref[rows, :] = dot(mr, fr) - dot(mi, fi)
+        outi_ref[rows, :] = dot(mr, fi) + dot(mi, fr)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("inverse", "batch_tile", "interpret",
-                                    "pack_parts"))
-def fft1d_planes(xr: jax.Array, xi: jax.Array, *, inverse: bool = False,
-                 batch_tile: int = DEFAULT_BATCH_TILE,
-                 interpret: bool = True,
-                 twiddle: Optional[Tuple[jax.Array, jax.Array]] = None,
-                 pack_parts: Optional[int] = None
-                 ) -> Tuple[jax.Array, jax.Array]:
-    """Batched last-axis FFT of (B, N) real/imag planes via the Pallas kernel.
+                   static_argnames=("inverse", "interpret", "precision"))
+def fft_cols(xr: jax.Array, xi: jax.Array, *, interpret: bool,
+             inverse: bool = False,
+             twiddle: Optional[Tuple[jax.Array, jax.Array]] = None,
+             precision=MATMUL_PRECISION) -> Tuple[jax.Array, jax.Array]:
+    """FFT along axis 1 of ``(G, N, M)`` real/imag planes.
 
-    Precision follows the planes' dtype: float32 in/out for f32 (and lower)
-    inputs, float64 end-to-end when the planes are f64 (x64 pipelines).
-
-    ``twiddle=(er, ei)`` fuses an extra elementwise complex multiply along
-    the output axis into the kernel epilogue (cos/sin planes of shape
-    ``(N,)``).  ``pack_parts=p`` fuses the pre-redistribution transpose-pack:
-    the result comes back as ``(B, p, N//p)`` — the ``p`` contiguous
-    per-destination blocks a following ``all_to_all(tiled=True)`` ships —
-    written directly by the kernel's final store.
-
-    ``B == 0`` returns an empty result of the right shape/dtype (a chunked
-    pipeline may legally feed an empty residual block).  ``interpret=True``
-    runs the kernel body as traced jax ops on CPU (this container has no
-    TPU); on real hardware pass ``interpret=False``.
+    ``twiddle=(er, ei)`` (cos/sin planes of shape ``(N,)``) multiplies the
+    result elementwise along the transformed axis, folded into the matmul
+    operand.  ``interpret`` is required: ``True`` runs the kernel body as
+    traced jax ops (the CPU path, chosen by ``kernels/ops.py``), ``False``
+    compiles it with Mosaic.  ``precision`` is the MXU contractions'
+    ``jax.lax.Precision``.
     """
-    b, n = xr.shape
-    n1, n2 = factorize(n)
+    g, n, m = xr.shape
     dt = jnp.result_type(xr.dtype, jnp.float32)
-    if pack_parts is not None and (pack_parts < 1 or n % pack_parts):
-        raise ValueError(
-            f"pack_parts={pack_parts} does not evenly split N={n}")
-    out_shape = ((b, n) if pack_parts is None
-                 else (b, pack_parts, n // pack_parts))
-    if b == 0:
-        # Zero-batch guard: min(batch_tile, 0) would build a zero grid and
-        # divide by zero in the pad computation below.
-        empty = jnp.zeros(out_shape, dt)
+    if not interpret and jnp.dtype(dt).itemsize == 8:
+        raise ValueError("the compiled Pallas FFT kernel has no float64 "
+                         "path (Mosaic has no f64); use backend='xla' or "
+                         "'matmul' for double precision")
+    if xr.size == 0:
+        empty = jnp.zeros(xr.shape, dt)
         return empty, empty
-    tb = min(batch_tile, b)
-    if b % tb != 0:
-        # pad batch to a tile multiple; trimmed below
-        pad = tb - b % tb
-        xr = jnp.pad(xr, ((0, pad), (0, 0)))
-        xi = jnp.pad(xi, ((0, pad), (0, 0)))
-    bp = xr.shape[0]
-    w = _planes(n1, n2, inverse, dtype=str(dt))
-
-    grid = (bp // tb,)
-    in_batch_spec = pl.BlockSpec((tb, n), lambda i: (i, 0))
-    const = lambda r, c: pl.BlockSpec((r, c), lambda i: (0, 0))
-    if pack_parts is None:
-        out_spec = in_batch_spec
-        out_block = (bp, n)
-    else:
-        out_spec = pl.BlockSpec((tb, pack_parts, n // pack_parts),
-                                lambda i: (i, 0, 0))
-        out_block = (bp, pack_parts, n // pack_parts)
-
-    in_specs = [in_batch_spec, in_batch_spec,
-                const(n1, n1), const(n1, n1),
-                const(n2, n2), const(n2, n2),
-                const(n1, n2), const(n1, n2)]
-    operands = [xr.astype(dt), xi.astype(dt),
-                *(jnp.asarray(p) for p in w)]
-    fused_twiddle = twiddle is not None
-    if fused_twiddle:
+    if not supports_length(n):
+        raise ValueError(
+            f"the Pallas FFT kernel does not take lines of length {n}: one "
+            f"program needs {vmem_bytes(n)} bytes of VMEM, over its "
+            f"{VMEM_LIMIT} (use backend='xla' or 'matmul')")
+    n1, n2 = factors(n)
+    sign = 1.0 if inverse else -1.0
+    op = jnp.asarray(_operand(n1, n2, inverse).astype(
+        np.complex128 if jnp.dtype(dt).itemsize == 8 else np.complex64))
+    if twiddle is not None:
         er, ei = twiddle
-        in_specs += [const(1, n), const(1, n)]
-        operands += [jnp.asarray(er).astype(dt).reshape(1, n),
-                     jnp.asarray(ei).astype(dt).reshape(1, n)]
-
-    outr, outi = pl.pallas_call(
-        functools.partial(_fft_kernel, n1=n1, n2=n2, inverse=inverse,
-                          fused_twiddle=fused_twiddle,
-                          pack_parts=pack_parts),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[out_spec, out_spec],
-        out_shape=[jax.ShapeDtypeStruct(out_block, dt),
-                   jax.ShapeDtypeStruct(out_block, dt)],
+        e = jax.lax.complex(jnp.asarray(er).astype(dt),
+                            jnp.asarray(ei).astype(dt))
+        # e[k1 + N1*k2] -> [k1, k2], scaling row k2 of M_k1
+        op = op * e.reshape(n2, n1).T[:, :, None]
+    # One (N, 128) block of lines per program; the edge block of a ragged
+    # M is masked.
+    block = pl.BlockSpec((None, n, MXU_DIM), lambda i, j: (i, 0, j))
+    const = pl.BlockSpec((n1, n2, n2), lambda i, j: (0, 0, 0))
+    out_struct = jax.ShapeDtypeStruct((g, n, m), dt)
+    return pl.pallas_call(
+        functools.partial(_fft_kernel, n1=n1, n2=n2,
+                          w1=_w1_constants(n1, sign), precision=precision),
+        grid=(g, pl.cdiv(m, MXU_DIM)),
+        in_specs=[block, block, const, const],
+        out_specs=[block, block],
+        out_shape=[out_struct, out_struct],
         interpret=interpret,
-    )(*operands)
-    return outr[:b], outi[:b]
+    )(xr.astype(dt), xi.astype(dt), jnp.real(op), jnp.imag(op))
+

@@ -3,88 +3,109 @@
 ``fft1d`` / ``ifft1d`` take complex (or real) arrays of any rank and
 transform along ``axis`` using the MXU four-step kernel.  They are the
 routing target of ``backend="pallas"``: ``core/transforms.apply_1d``
-dispatches every C2C line of the pallas backend here, and
-``core/pipeline._stage_transform`` additionally threads the fused
-epilogues through (``twiddle=`` for the DCT-II/DST-II phase,
-``pack_parts=`` for the transpose-pack feeding the next ``RedistHop``'s
-all_to_all).
+dispatches every C2C line of the pallas backend here, with the DCT-II/DST-II
+phase fused into the kernel (``twiddle=``).
 
-``interpret`` defaults to ``None`` = "interpret unless running on a TPU":
-off-TPU (this CPU container, CI) the kernel body executes as traced jax
-ops so the suite stays hermetic; on real hardware the same call sites
-compile the Mosaic kernel.  Output dtype follows the input — complex64
-in/out for single precision, complex128 end-to-end under ``jax.enable_x64``.
+``interpret`` defaults to ``None`` = "interpret on the CPU platform only":
+there (the test suite, CI) the kernel body executes as traced jax ops; on
+every other platform the same call sites compile the Mosaic kernel, and a
+kernel the compiler refuses is an error, never a silent interpreter run.
+Output dtype follows the input — complex64 in/out for single precision,
+complex128 end-to-end under ``jax.enable_x64`` (interpret mode only).
+:func:`unsupported` says, before anything is traced, which plans the kernel
+cannot run; the planner and the tuner ask it.
 """
 from __future__ import annotations
 
-from typing import Optional
+import math
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 
-from .fft_matmul import fft1d_planes
+from .fft_matmul import fft_cols, line_flops, supports_length, vmem_bytes
+from .precision import MATMUL_PRECISION
+
+__all__ = ["fft1d", "ifft1d", "interpret_mode", "line_flops",
+           "supports_dtype", "supports_length", "unsupported"]
 
 
-def _resolve_interpret(interpret: Optional[bool]) -> bool:
-    if interpret is not None:
-        return interpret
-    return jax.default_backend() != "tpu"
+def interpret_mode() -> bool:
+    """Whether the kernel runs interpreted here: on the CPU platform only."""
+    return jax.default_backend() == "cpu"
+
+
+def supports_dtype(dtype) -> bool:
+    """Whether the kernel can run ``dtype`` on this platform: the compiled
+    kernel has no float64 path (Mosaic has no f64)."""
+    return interpret_mode() or jnp.finfo(dtype).bits < 64
+
+
+def unsupported(lengths: Sequence[int], dtype) -> Optional[str]:
+    """Why the kernel cannot run lines of these ``lengths`` in ``dtype``
+    on this platform, or ``None`` when it can."""
+    if not supports_dtype(dtype):
+        return (f"has no {jnp.dtype(dtype)} path on {jax.default_backend()} "
+                f"(Mosaic has no f64)")
+    too_long = sorted({n for n in lengths if not supports_length(n)})
+    if too_long:
+        return (f"does not take lines of length {too_long}: one kernel "
+                f"program would need {vmem_bytes(too_long[0])} bytes of VMEM")
+    return None
 
 
 def _apply(x: jax.Array, axis: int, *, inverse: bool,
            interpret: Optional[bool] = None,
            twiddle: Optional[jax.Array] = None,
-           pack_parts: Optional[int] = None) -> jax.Array:
-    interpret = _resolve_interpret(interpret)
+           precision=MATMUL_PRECISION) -> jax.Array:
+    interpret = interpret_mode() if interpret is None else interpret
     axis = axis % x.ndim
-    xm = jnp.moveaxis(x, axis, -1)
-    lead = xm.shape[:-1]
-    n = xm.shape[-1]
+    n = x.shape[axis]
     cdt = jnp.result_type(x.dtype, jnp.complex64)
     rdt = jnp.finfo(cdt).dtype
-    if xm.size == 0:
-        # Empty batch (or empty line): nothing to transform — mirror the
-        # kernel's own guard so callers get the right shape/dtype back.
-        # (Checked before the flatten: reshape(-1, 0) is itself an error.)
-        return jnp.moveaxis(jnp.zeros(lead + (n,), cdt), -1, axis)
-    flat_r = jnp.real(xm).astype(rdt).reshape(-1, n)
-    flat_i = jnp.imag(xm).astype(rdt).reshape(-1, n) if jnp.iscomplexobj(xm) \
-        else jnp.zeros_like(flat_r)
+    if x.size == 0:
+        # Empty batch (or empty line): nothing to transform.
+        return jnp.zeros(x.shape, cdt)
+    # The kernel transforms axis 1 of (G, N, M): a free reshape for any
+    # axis but the last, which is transposed so the lines run on lanes.
+    g = math.prod(x.shape[:axis])
+    last = axis == x.ndim - 1
+    xc = x.reshape(g, n).T[None] if last else x.reshape(g, n, -1)
+    planes_r = jnp.real(xc).astype(rdt)
+    planes_i = (jnp.imag(xc).astype(rdt) if jnp.iscomplexobj(xc)
+                else jnp.zeros_like(planes_r))
     tw = None
     if twiddle is not None:
         t = jnp.asarray(twiddle).reshape(-1)
         tw = (jnp.real(t).astype(rdt), jnp.imag(t).astype(rdt))
-    outr, outi = fft1d_planes(flat_r, flat_i, inverse=inverse,
-                              interpret=interpret, twiddle=tw,
-                              pack_parts=pack_parts)
-    out = jax.lax.complex(outr, outi).reshape(lead + (n,))
-    return jnp.moveaxis(out, -1, axis)
+    outr, outi = fft_cols(planes_r, planes_i, inverse=inverse,
+                          interpret=interpret, twiddle=tw,
+                          precision=precision)
+    out = jax.lax.complex(outr, outi)
+    return (out[0].T if last else out).reshape(x.shape)
 
 
 def fft1d(x: jax.Array, axis: int = -1, *,
           interpret: Optional[bool] = None,
           twiddle: Optional[jax.Array] = None,
-          pack_parts: Optional[int] = None) -> jax.Array:
+          precision=MATMUL_PRECISION) -> jax.Array:
     """Forward FFT along ``axis`` via the Pallas MXU kernel.
 
-    ``twiddle`` — optional complex ``(n,)`` phase fused into the kernel
-    epilogue (the result is ``twiddle * fft(x)`` elementwise along ``axis``).
-    ``pack_parts`` — fuse the pre-all_to_all transpose-pack: the kernel
-    stores the transformed axis pre-split into ``pack_parts`` contiguous
-    blocks; the returned array still has the logical shape (the packed
-    layout is a free reshape of the kernel's output buffer).
+    ``twiddle`` — optional complex ``(n,)`` phase fused into the kernel's
+    matmul operand (the result is ``twiddle * fft(x)`` elementwise along
+    ``axis``).  ``precision`` — the MXU contractions' ``jax.lax.Precision``
+    (``chip_smoke.py --precision-probe`` compares them).
     """
     return _apply(x, axis, inverse=False, interpret=interpret,
-                  twiddle=twiddle, pack_parts=pack_parts)
+                  twiddle=twiddle, precision=precision)
 
 
 def ifft1d(x: jax.Array, axis: int = -1, *,
            interpret: Optional[bool] = None,
-           twiddle: Optional[jax.Array] = None,
-           pack_parts: Optional[int] = None) -> jax.Array:
+           twiddle: Optional[jax.Array] = None) -> jax.Array:
     """Inverse FFT along ``axis`` via the Pallas MXU kernel.
 
-    Accepts the same fused-epilogue options as :func:`fft1d`.
+    ``twiddle`` as for :func:`fft1d`.
     """
     return _apply(x, axis, inverse=True, interpret=interpret,
-                  twiddle=twiddle, pack_parts=pack_parts)
+                  twiddle=twiddle)

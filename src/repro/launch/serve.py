@@ -21,6 +21,7 @@ import numpy as np
 from repro.configs import get_config, smoke_config
 from repro.distributed.fault import StepWatchdog
 from repro.distributed.sharding import MeshRules
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import (build_params, make_decode_step,
                                 make_prefill_step)
@@ -88,6 +89,7 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--max-new", type=int, default=16)
     args = ap.parse_args()
+    enable_compile_cache()
     out = serve(args.arch, smoke=args.smoke, requests=args.requests,
                 batch=args.batch, prompt_len=args.prompt_len,
                 max_new=args.max_new)

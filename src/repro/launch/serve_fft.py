@@ -1,16 +1,13 @@
-import os
-if "XLA_FLAGS" not in os.environ:
-    # Standalone/CI runs serve on fake host devices; set before jax init.
-    os.environ["XLA_FLAGS"] = (
-        "--xla_force_host_platform_device_count="
-        + os.environ.get("SERVE_FFT_DEVICES", "8"))
-
 """Spectral serving driver: warmed, bucketed, loss-tolerant FFT service.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.serve_fft --smoke --check
   PYTHONPATH=src python -m repro.launch.serve_fft --smoke --requests 24 \
       --lose 3 --json artifacts/serve_fft_metrics.json
+
+It serves on the devices the process has.  On the CPU, give it several
+with ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` set before
+python starts.
 
 The driver plays one full service lifetime:
 
@@ -20,7 +17,8 @@ The driver plays one full service lifetime:
    known-but-untuned secondary grid);
 2. run rounds of deterministic mixed-shape traffic (bucket-exact, odd
    shapes that pad, a second family) through submit/drain;
-3. mid-stream, with requests already queued, **lose ``--lose`` devices**:
+3. mid-stream, with requests already queued, **lose ``--lose`` devices**
+   (default: half of them, none on a one-device host):
    the service re-shapes the survivors via ``choose_fft_mesh_shape``,
    re-plans every family, and the pending round completes degraded;
 4. verify every completed request against the NumPy reference (padded
@@ -32,6 +30,7 @@ The driver plays one full service lifetime:
 """
 import argparse
 import json
+import os
 import time
 
 import jax
@@ -41,6 +40,7 @@ import numpy as np
 from repro.core.plan import TuningCache
 from repro.core.tuner import tune
 from repro.distributed.fault import choose_fft_mesh_shape
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import FFTService
 
 # Smoke traffic: two C2C families + odd shapes that pad into the first.
@@ -81,11 +81,18 @@ def verify_result(x, res, *, atol=1e-4):
     return float(np.max(np.abs(np.asarray(res.y) - ref))) / scale
 
 
-def serve_fft(*, requests=24, round_size=8, lose=3, seed=0,
+def serve_fft(*, requests=24, round_size=8, lose=None, seed=0,
               wisdom=None, json_path=None, check=False,
               hit_rate_min=0.8, verify="off", verbose=True):
     rng = np.random.default_rng(seed)
     mesh = make_mesh(dims=PRIMARY_GRID + SECONDARY_GRID)
+    n_dev = mesh.devices.size
+    if lose is None:
+        lose = n_dev // 2
+    if not 0 <= lose < n_dev:
+        raise SystemExit(
+            f"[serve_fft] --lose {lose} must leave at least one of the "
+            f"{n_dev} devices serving: pass 0 <= --lose < {n_dev}")
     cache = TuningCache(path=wisdom)
     # Yesterday's serving day: the dominant grid is already tuned+persisted.
     tune(PRIMARY_GRID, mesh, mode="auto", cache=cache)
@@ -206,8 +213,9 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true", default=True)
     ap.add_argument("--requests", type=int, default=24)
     ap.add_argument("--round-size", type=int, default=8)
-    ap.add_argument("--lose", type=int, default=3,
-                    help="devices to drop mid-stream (0 disables)")
+    ap.add_argument("--lose", type=int, default=None,
+                    help="devices to drop mid-stream (0 disables; default "
+                         "half of them)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--wisdom", type=str, default=None,
                     help="wisdom-file path (default: in-memory)")
@@ -221,6 +229,7 @@ def main(argv=None):
                     help="statically check every drain's planned segment "
                          "order before dispatch (strict: raise on findings)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     serve_fft(requests=args.requests, round_size=args.round_size,
               lose=args.lose, seed=args.seed, wisdom=args.wisdom,
               json_path=args.json, check=args.check,

@@ -26,6 +26,7 @@ from repro.configs import get_config, smoke_config
 from repro.data.pipeline import SyntheticLM
 from repro.distributed.fault import StepWatchdog
 from repro.distributed.sharding import MeshRules, to_named_shardings
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import build_params, make_train_step, opt_pspecs
 from repro.models.config import ShapeConfig
@@ -99,6 +100,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--lr", type=float, default=3e-4)
     args = ap.parse_args()
+    enable_compile_cache()
     out = train(args.arch, steps=args.steps, seq_len=args.seq_len,
                 global_batch=args.global_batch, smoke=args.smoke,
                 ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every,

@@ -106,8 +106,10 @@ class PlanWarmer:
                                     tuning="auto", tune_cache=self.cache)
                     if prebuild_segments:
                         rep.segments_prebuilt += len(plan.segments())
-                except Exception:
-                    # Foreign or stale wisdom must never block startup.
+                except (ValueError, KeyError):
+                    # Stale wisdom (a problem this mesh or platform can no
+                    # longer plan) must not block startup; a compile or
+                    # runtime failure is an error and propagates.
                     rep.skipped.append(prob["key"])
                     continue
                 self.plans[(prob["grid"], prob["kinds"], prob["dtype"],

@@ -12,8 +12,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 @pytest.fixture(scope="session")
 def cpu_mesh():
-    # jax 0.4.x has neither jax.sharding.AxisType nor the axis_types kwarg;
-    # repro.compat.make_mesh papers over both.
+    # Meshes come from repro.compat.make_mesh, the one place that spells
+    # jax's mesh API (lint rule REP001).
     from repro.compat import AxisType, make_mesh
     return make_mesh((1, 1), ("data", "model"),
                      axis_types=(AxisType.Auto,) * 2)

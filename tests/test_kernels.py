@@ -1,5 +1,5 @@
 """Pallas kernel validation: shape/dtype sweep vs the pure-jnp oracle,
-executed with interpret=True (no TPU in this container)."""
+executed with interpret=True (the CPU platform's mode)."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -9,7 +9,8 @@ except ImportError:  # hermetic container: fixed-seed shim
     from _propcheck import given, settings, strategies as st
 
 from conftest import run_subprocess
-from repro.kernels.fft_matmul import fft1d_planes
+from repro.kernels.fft_matmul import (VMEM_LIMIT, fft_cols, line_flops,
+                                      supports_length, vmem_bytes)
 from repro.kernels.ops import fft1d, ifft1d
 from repro.kernels.ref import fft1d_planes_ref, fft1d_ref, ifft1d_ref
 
@@ -50,11 +51,13 @@ def test_kernel_axis_handling(axis):
 def test_kernel_plane_dtypes(dtype):
     xr = rng.standard_normal((4, 32)).astype(dtype)
     xi = rng.standard_normal((4, 32)).astype(dtype)
-    outr, outi = fft1d_planes(jnp.asarray(xr), jnp.asarray(xi))
+    # the kernel transforms axis 1 of (G, N, M): lines on lanes
+    outr, outi = fft_cols(jnp.asarray(xr).T[None], jnp.asarray(xi).T[None],
+                          interpret=True)
     refr, refi = fft1d_planes_ref(jnp.asarray(xr), jnp.asarray(xi))
-    np.testing.assert_allclose(np.asarray(outr), np.asarray(refr),
+    np.testing.assert_allclose(np.asarray(outr[0]).T, np.asarray(refr),
                                rtol=1e-3, atol=1e-3)
-    np.testing.assert_allclose(np.asarray(outi), np.asarray(refi),
+    np.testing.assert_allclose(np.asarray(outi[0]).T, np.asarray(refi),
                                rtol=1e-3, atol=1e-3)
 
 
@@ -132,14 +135,14 @@ print("inv_ok", int(np.allclose(np.asarray(yi), np.fft.ifft(x, axis=0),
 def test_kernel_empty_batch_regression():
     """Regression: b == 0 used to build a zero grid / divide by zero in the
     pad computation.  Must return an empty result of the right shape/dtype."""
-    outr, outi = fft1d_planes(jnp.zeros((0, 16), jnp.float32),
-                              jnp.zeros((0, 16), jnp.float32))
-    assert outr.shape == (0, 16) and outi.shape == (0, 16)
+    outr, outi = fft_cols(jnp.zeros((1, 16, 0), jnp.float32),
+                          jnp.zeros((1, 16, 0), jnp.float32), interpret=True)
+    assert outr.shape == (1, 16, 0) and outi.shape == (1, 16, 0)
     assert outr.dtype == jnp.float32
-    # packed variant keeps the packed block shape
-    pr, _ = fft1d_planes(jnp.zeros((0, 16), jnp.float32),
-                         jnp.zeros((0, 16), jnp.float32), pack_parts=4)
-    assert pr.shape == (0, 4, 4)
+    # an empty leading batch as well
+    er, _ = fft_cols(jnp.zeros((0, 16, 4), jnp.float32),
+                     jnp.zeros((0, 16, 4), jnp.float32), interpret=True)
+    assert er.shape == (0, 16, 4)
     # the ops wrapper guards the same way (any-rank empty input)
     y = fft1d(jnp.zeros((0, 8, 16), jnp.complex64), -1)
     assert y.shape == (0, 8, 16) and y.dtype == jnp.complex64
@@ -161,15 +164,23 @@ def test_kernel_fused_twiddle_epilogue():
                                rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.parametrize("parts", [2, 4, 8])
-def test_kernel_pack_parts_epilogue(parts):
-    """pack_parts stores the output pre-split per destination; the logical
-    result must be unchanged, and illegal parts must raise."""
-    x = (rng.standard_normal((5, 32)) + 1j * rng.standard_normal((5, 32))
-         ).astype(np.complex64)
-    got = np.asarray(fft1d(jnp.asarray(x), pack_parts=parts))
-    np.testing.assert_allclose(got, np.fft.fft(x, axis=-1),
-                               rtol=1e-4, atol=1e-3)
-    with pytest.raises(ValueError, match="pack_parts"):
-        fft1d_planes(jnp.zeros((2, 32), jnp.float32),
-                     jnp.zeros((2, 32), jnp.float32), pack_parts=5)
+@pytest.mark.parametrize("n,takes", [
+    (2048, True),     # the largest power of two that fits VMEM
+    (2560, True),     # (20, 128): 15 MiB
+    (4096, False),    # 24 MiB: Mosaic runs out of VMEM compiling it
+    (904, True),      # (8, 113)
+    (905, False),     # 5 * 181: the dense (1, 905) split is over the limit
+    (32768, False),   # would be a dense N x N operand
+])
+def test_kernel_line_length_limit(n, takes):
+    """Lines whose kernel program does not fit VMEM are refused before any
+    operand is built, in interpret mode as on the chip; the kernel's flop
+    count splits into MXU and VPU work."""
+    assert supports_length(n) is takes
+    assert (vmem_bytes(n) <= VMEM_LIMIT) is takes
+    mxu, vpu = line_flops(n)
+    assert mxu > 0 and vpu > 0
+    if not takes:
+        planes = jnp.zeros((1, n, 1), jnp.float32)
+        with pytest.raises(ValueError, match=f"lines of length {n}"):
+            fft_cols(planes, planes, interpret=True)

@@ -246,35 +246,39 @@ print("match_xla", int(np.allclose(got / s, ref / s, atol=2e-4)))
     assert vals["match_xla"] == "1"
 
 
-def test_fused_pack_epilogue_identical_to_unfused():
-    """Acceptance: the fused twiddle+pack variant produces an identical
-    pipeline result to the unfused path (REPRO_PALLAS_FUSE=0).  Uses
-    build_pipeline directly: the env toggle is not part of the plan key,
-    so the compiled-plan cache must be bypassed."""
-    out = run_subprocess("""
-import os, numpy as np, jax, jax.numpy as jnp
-from repro.compat import make_mesh
-from repro.core.decomp import pencil_nd
-from repro.core.pipeline import build_pipeline, make_spec
-mesh = make_mesh((2, 4), ("data", "model"))
-r = np.random.default_rng(7)
-x = (r.standard_normal((16, 16, 32)) + 1j * r.standard_normal((16, 16, 32))
-     ).astype(np.complex64)
-xj = jnp.asarray(x)
-dec = pencil_nd(("data", "model"), 3)
-spec = make_spec(mesh, (16, 16, 32), dec, ("fft",) * 3, backend="pallas")
+def test_compiled_pallas_refuses_float64(cpu_mesh, monkeypatch):
+    """Off the CPU platform the Pallas kernel is compiled by Mosaic, which
+    has no f64: a complex128 pallas plan is refused when it is planned,
+    and the tuner never enumerates one."""
+    import jax.numpy as jnp
 
-os.environ["REPRO_PALLAS_FUSE"] = "1"
-fused = jax.jit(build_pipeline(mesh, spec))(xj)
-os.environ["REPRO_PALLAS_FUSE"] = "0"
-unfused = jax.jit(build_pipeline(mesh, spec))(xj)
-print("bitwise_identical",
-      int(np.array_equal(np.asarray(fused), np.asarray(unfused))))
-ref = jnp.fft.fftn(xj, axes=(0, 1, 2))
-s = float(jnp.max(jnp.abs(ref)))
-print("match_fftn", int(np.allclose(np.asarray(fused) / s,
-                                    np.asarray(ref) / s, atol=2e-4)))
-""")
-    vals = dict(l.split() for l in out.strip().splitlines())
-    assert vals["bitwise_identical"] == "1"
-    assert vals["match_fftn"] == "1"
+    from repro.core.api import plan_fft
+    from repro.core.plan import TuningCache
+    from repro.core.tuner import tune
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "interpret_mode", lambda: False)
+    assert ops.supports_dtype(jnp.complex64)
+    assert not ops.supports_dtype(jnp.complex128)
+    with pytest.raises(ValueError, match="no complex128 path"):
+        plan_fft(cpu_mesh, (8, 8, 8), dtype=jnp.complex128,
+                 backend="pallas")
+    with pytest.raises(ValueError, match="no valid plan"):
+        tune((8, 8, 8), cpu_mesh, mode="heuristic", dtype=jnp.complex128,
+             backends=("pallas",), cache=TuningCache())
+
+
+def test_pallas_refuses_lines_over_its_vmem(cpu_mesh):
+    """A grid with lines the kernel cannot take (N = 4096 does not fit its
+    VMEM) is refused when a pallas plan is made, and the tuner drops
+    pallas for it instead of proposing a plan that cannot compile."""
+    from repro.core.api import plan_fft
+    from repro.core.plan import TuningCache
+    from repro.core.tuner import tune
+    with pytest.raises(ValueError, match=r"lines of length \[4096\]"):
+        plan_fft(cpu_mesh, (4096, 4, 4), backend="pallas")
+    with pytest.raises(ValueError, match="no valid plan"):
+        tune((4096, 4, 4), cpu_mesh, mode="heuristic",
+             backends=("pallas",), cache=TuningCache())
+    plan = tune((4096, 4, 4), cpu_mesh, mode="heuristic",
+                cache=TuningCache())
+    assert plan.backend != "pallas"

@@ -72,3 +72,31 @@ print("a2a_bytes", per_kind.get("all-to-all", 0))
     full = v1 * 2 / 1 + v2 * 4 / 3
     got = float(vals["a2a_bytes"])
     assert got == pytest.approx(full, rel=0.35), (got, full)
+
+
+class _FakeDevice:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+@pytest.mark.parametrize("platform,kind,want", [
+    ("cpu", "cpu", CPU_CORE),
+    ("tpu", "TPU v5 lite", TPU_V5E),
+    ("tpu", "TPU v4", None),          # a TPU with no row in the table
+])
+def test_default_machine_keys_on_device_kind(monkeypatch, platform, kind,
+                                             want):
+    """Machine constants come from ``device_kind``: an accelerator kind
+    missing from ``ACCELERATORS`` raises instead of borrowing v5e's."""
+    import jax
+
+    from repro.core import tuner
+    from repro.core.perfmodel import default_machine
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: [_FakeDevice(platform, kind)])
+    if want is None:
+        with pytest.raises(ValueError, match="TPU v4"):
+            default_machine()
+    else:
+        assert default_machine() is want
+        assert tuner.default_machine() is want

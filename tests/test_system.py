@@ -59,3 +59,25 @@ def test_generation_loop(cpu_mesh, rules):
             cur = nxt[:, None].astype(jnp.int32)
             outs.append(int(nxt[0]))
         assert all(0 <= t < cfg.padded_vocab for t in outs)
+
+
+@pytest.mark.parametrize("env_dir", [None, "given"])
+def test_compile_cache_directory(monkeypatch, tmp_path, env_dir):
+    """The entry points' compile cache: ``$JAX_COMPILATION_CACHE_DIR`` when
+    set, else the fixed ``.jax_cache/`` at the checkout root."""
+    import os
+
+    from repro.launch.compile_cache import enable_compile_cache
+    root = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = os.path.join(os.path.realpath(root), ".jax_cache")
+    else:
+        want = str(tmp_path / env_dir)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        assert enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

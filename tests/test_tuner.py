@@ -396,6 +396,70 @@ print("nondefault", int((plan.decomp, plan.backend, plan.n_chunks)
     assert vals["decomp"] == "slab"
 
 
+def test_auto_tune_passes_over_plans_that_do_not_fit_device_memory():
+    """Auto tuning compiles a candidate before it runs it and passes over
+    any whose arguments, output and temporaries exceed a device's memory
+    limit; with no plan that fits, it says so instead of running one."""
+    out = run_subprocess(TUNE_COMMON + """
+import repro.core.tuner as T
+from repro.core.perfmodel import CPU_CORE
+cands = T.enumerate_candidates((8, 8, 16), mesh, ("fft",) * 3)
+base = T._default_candidate(cands)
+def footprint(cand):
+    spec = T._spec_for(mesh, (8, 8, 16), cand.decomp, cand.mesh_axes,
+                       ("fft",) * 3, cand.backend, cand.spec_chunks, False, 0,
+                       dim_groups=cand.dim_groups)
+    m = T.compile_pipeline(mesh, spec, dtype=jnp.complex64).memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes)
+limit = footprint(base)
+T.device_memory_limit = lambda mesh: limit
+plan = T.tune((8, 8, 16), mesh, cache=TuningCache(), machine=CPU_CORE,
+              repeats=1)
+won = T.Candidate(decomp=plan.decomp, mesh_axes=plan.mesh_axes,
+                  backend=plan.backend, n_chunks=plan.n_chunks,
+                  dim_groups=plan.dim_groups,
+                  chunk_schedule=plan.chunk_schedule)
+print("winner_fits", int(footprint(won) <= limit))
+T.device_memory_limit = lambda mesh: 1
+try:
+    T.tune((8, 8, 16), mesh, cache=TuningCache(), machine=CPU_CORE)
+    print("refused", 0)
+except ValueError as e:
+    print("refused", int("fits the 1 bytes" in str(e)))
+""")
+    vals = dict(l.split() for l in out.strip().splitlines())
+    assert vals["winner_fits"] == "1"
+    assert vals["refused"] == "1"
+
+
+def test_model_only_chunk_choice_is_capped():
+    """The cost model cannot see memory, so heuristic tuning and
+    ``propose_chunk_schedule`` stop at MODEL_MAX_CHUNKS per hop even where
+    the model would chunk deeper."""
+    out = run_subprocess(TUNE_COMMON + """
+import repro.core.tuner as T
+from repro.core.decomp import make_decomposition
+from repro.core.perfmodel import TPU_V5E
+from repro.core.pipeline import make_spec
+grid = (1024, 1024, 1024)
+spec = make_spec(mesh, grid, make_decomposition("pencil", ("data", "model"), 3),
+                 ("fft",) * 3, backend="xla", n_chunks=1)
+sizes = {"data": 2, "model": 4}
+print("uncapped", max(T.propose_chunk_schedule(spec, sizes, TPU_V5E,
+                                               max_chunks=1024)))
+print("proposed", max(T.propose_chunk_schedule(spec, sizes, TPU_V5E)))
+plan = T.tune(grid, mesh, mode="heuristic", machine=TPU_V5E)
+print("heuristic", max(plan.chunk_schedule or (plan.n_chunks,)))
+""")
+    vals = {k: int(v) for k, v in
+            (l.split() for l in out.strip().splitlines())}
+    from repro.core.tuner import MODEL_MAX_CHUNKS
+    assert vals["uncapped"] > MODEL_MAX_CHUNKS
+    assert vals["proposed"] == MODEL_MAX_CHUNKS
+    assert vals["heuristic"] <= MODEL_MAX_CHUNKS
+
+
 def test_restricted_tune_does_not_poison_cache():
     """Acceptance: a restricted search (backends subset / chunk cap) must
     never persist its winner under the unrestricted key, so a later
