@@ -1,0 +1,7 @@
+"""1 - busy union / window, from the device trace, mean over the chips, %."""
+
+
+def read(run):
+    if run.trace is None:
+        return None
+    return 100.0 * (1.0 - run.trace.busy_s / run.trace.window_s)
