@@ -493,17 +493,21 @@ class DistributedFFT:
                 donate: bool = False) -> jax.Array:
         """Forward transform.  ``sharded_in=True`` trusts ``x`` to already
         carry ``self.in_sharding`` and skips the entry ``device_put``;
-        ``donate=True`` donates the input buffer to the computation."""
-        return self._execute(x, inverse=False, sharded_in=sharded_in,
-                             donate=donate)
+        ``donate=True`` donates the input buffer to the computation.
+        Runs under the host span ``repro.forward``."""
+        with jax.profiler.TraceAnnotation("repro.forward"):
+            return self._execute(x, inverse=False, sharded_in=sharded_in,
+                                 donate=donate)
 
     def inverse(self, y: jax.Array, *, sharded_in: bool = False,
                 donate: bool = False) -> jax.Array:
         """Inverse transform.  A forward output is already laid out in the
         inverse input sharding, so ``plan.inverse(plan.forward(x),
-        sharded_in=True)`` round-trips with zero redundant copies."""
-        return self._execute(y, inverse=True, sharded_in=sharded_in,
-                             donate=donate)
+        sharded_in=True)`` round-trips with zero redundant copies.  Runs
+        under the host span ``repro.inverse``."""
+        with jax.profiler.TraceAnnotation("repro.inverse"):
+            return self._execute(y, inverse=True, sharded_in=sharded_in,
+                                 donate=donate)
 
     def __call__(self, x: jax.Array, **kw) -> jax.Array:
         return self.forward(x, **kw)
@@ -761,18 +765,23 @@ def plan_memo_stats() -> Dict[str, int]:
 
 
 def plan_cache_stats() -> Dict[str, Dict[str, Any]]:
-    """Public counters of both in-process plan-caching layers.
+    """Public counters of both in-process plan-caching layers, and of XLA.
 
     ``compiled`` — the LRU :data:`~repro.core.plan.GLOBAL_PLAN_CACHE` of
     compiled executables (fused pipelines and stage segments);
     ``memo`` — the wrappers' plan-handle memo (``fftnd``/``poisson_solve``).
     Each carries ``hits``/``misses``/``evictions`` plus occupancy, so a
     serving metrics layer can report plan-cache health without reaching
-    into private counters.
+    into private counters.  ``xla`` — every jit of the process, the
+    program's and others': ``traces``, ``compiles`` (cache loads
+    excluded), ``cache_loads`` from the persistent compilation cache and
+    ``compile_s`` (:func:`~repro.core.plan.xla_compile_stats`); a steady
+    loop adds nothing to them.
     """
-    from .plan import GLOBAL_PLAN_CACHE
+    from .plan import GLOBAL_PLAN_CACHE, xla_compile_stats
     return {"compiled": GLOBAL_PLAN_CACHE.stats(),
-            "memo": plan_memo_stats()}
+            "memo": plan_memo_stats(),
+            "xla": xla_compile_stats()}
 
 
 def _wrapper_plan(mesh: Mesh, grid, kinds, batch_shape, dtype, decomp,
@@ -976,18 +985,24 @@ class PoissonSolver:
     def solve(self, rhs: jax.Array, *, sharded_in: bool = False,
               donate: bool = False) -> jax.Array:
         """One pressure solve; the null (mean) mode is zeroed per batch
-        element and real input comes back real."""
-        real_in = not jnp.iscomplexobj(rhs)
-        xk = self.plan.forward(rhs, sharded_in=sharded_in, donate=donate)
-        scaled = xk / self._lam_for(xk.dtype)
-        # Zero the null (mean) mode explicitly — indexing only the trailing
-        # 3 spectral dims so every leading batch element is zeroed, not
-        # just batch index 0.
-        scaled = scaled.at[..., 0, 0, 0].set(jnp.zeros((), scaled.dtype))
-        phi = self.plan.inverse(scaled)
-        if real_in and jnp.iscomplexobj(phi):
-            phi = jnp.real(phi)
-        return phi
+        element and real input comes back real.  Runs under the host span
+        ``repro.solve``, its divide and null-mode pin under
+        ``repro.solve.scale``."""
+        with jax.profiler.TraceAnnotation("repro.solve"):
+            real_in = not jnp.iscomplexobj(rhs)
+            xk = self.plan.forward(rhs, sharded_in=sharded_in,
+                                   donate=donate)
+            with jax.profiler.TraceAnnotation("repro.solve.scale"):
+                scaled = xk / self._lam_for(xk.dtype)
+                # Zero the null (mean) mode explicitly — indexing only the
+                # trailing 3 spectral dims so every leading batch element
+                # is zeroed, not just batch index 0.
+                scaled = scaled.at[..., 0, 0, 0].set(
+                    jnp.zeros((), scaled.dtype))
+            phi = self.plan.inverse(scaled)
+            if real_in and jnp.iscomplexobj(phi):
+                phi = jnp.real(phi)
+            return phi
 
     def __call__(self, rhs: jax.Array, **kw) -> jax.Array:
         return self.solve(rhs, **kw)

@@ -30,6 +30,15 @@ call.  Each segment carries a sharding-in/sharding-out contract
 (``segment_in_spec``/``segment_out_spec``); boundary shapes/dtypes come
 from abstract evaluation (``segment_structs``).  ``core.executor``
 interleaves segments of *different* plans on this contract.
+
+For the device trace, programs are named ``repro_fft_forward`` /
+``repro_fft_inverse`` (``_seg<j>`` for segments) and, inside the shard_map
+body, each stage's local transform runs under the ``jax.named_scope``
+``stage<k>`` and each hop's exchange under ``hop<i>``, both indexed in the
+direction's execution order.  Scopes are trace-time metadata: they cost
+nothing at run time.  The body's parameter is ``repro_fft_in``: the ops
+that read it carry its name and no program prefix, and no other program
+names a parameter so.
 """
 from __future__ import annotations
 
@@ -285,12 +294,29 @@ def make_spec(mesh: Mesh, grid: Tuple[int, ...], decomp: Decomposition,
     return dataclasses.replace(spec, chunk_schedule=clamped)
 
 
+def _named(fn: Callable, name: str) -> Callable:
+    """``fn`` under ``name``: ``jax.jit`` names its program after the
+    function, so the device trace tells each plan executable apart."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
+def program_name(spec: PipelineSpec, segment: Optional[int] = None) -> str:
+    """``repro_fft_forward`` / ``repro_fft_inverse``, with ``_seg<j>`` for
+    stage segment ``j``."""
+    name = "repro_fft_inverse" if spec.inverse else "repro_fft_forward"
+    return name if segment is None else f"{name}_seg{segment}"
+
+
 def _stage_transform(spec: PipelineSpec, stage: StageLayout,
-                     is_first: bool, is_last: bool) -> Callable:
-    """Local transform for one stage (may cover 2 dims for slabs)."""
+                     index: int) -> Callable:
+    """Local transform for one stage (may cover 2 dims for slabs), under
+    the device scope ``stage<index>`` (execution order)."""
     off = spec.spatial_offset
 
-    def run(x: jax.Array) -> jax.Array:
+    @jax.named_scope(f"stage{index}")
+    def run(repro_fft_in: jax.Array) -> jax.Array:
+        x = repro_fft_in
         dims = stage.fft_dims if not spec.inverse else stage.fft_dims[::-1]
         for d in dims:
             kind = spec.kinds[d]
@@ -322,13 +348,12 @@ def _local_pipeline(spec: PipelineSpec) -> Callable:
     """The per-device function to be shard_map'd."""
     stages, redists = spec.stage_order()
 
-    def run(x: jax.Array) -> jax.Array:
+    def run(repro_fft_in: jax.Array) -> jax.Array:
         off = spec.spatial_offset
-        x = _stage_transform(spec, stages[0], True, len(stages) == 1)(x)
+        x = _stage_transform(spec, stages[0], 0)(repro_fft_in)
         for i, hop in enumerate(redists):
             nxt_stage = stages[i + 1]
-            nxt = _stage_transform(spec, nxt_stage, False,
-                                   i + 1 == len(stages) - 1)
+            nxt = _stage_transform(spec, nxt_stage, i + 1)
             # The chunk dim must dodge the fused transform's dims, or the
             # per-chunk FFT would run over a split dim (the inverse-slab
             # bug); redistribute falls back to bulk when none is legal.
@@ -344,11 +369,12 @@ def _local_pipeline(spec: PipelineSpec) -> Callable:
 
 
 def build_pipeline(mesh: Mesh, spec: PipelineSpec) -> Callable:
-    """shard_map the local pipeline over the mesh.  jit-compatible."""
+    """shard_map the local pipeline over the mesh.  jit-compatible; named
+    :func:`program_name`."""
     fn = shard_map(_local_pipeline(spec), mesh=mesh,
                    in_specs=spec.in_spec(), out_specs=spec.out_spec(),
                    check_vma=False)
-    return fn
+    return _named(fn, program_name(spec))
 
 
 def input_struct(mesh: Mesh, spec: PipelineSpec,
@@ -468,15 +494,15 @@ def _local_segment(spec: PipelineSpec, index: int) -> Callable:
         raise ValueError(f"segment index {index} out of range for "
                          f"{len(stages)} stages")
     off = spec.spatial_offset
-    last = index == len(stages) - 1
-    stage_fn = _stage_transform(spec, stages[index], index == 0, last)
+    stage_fn = _stage_transform(spec, stages[index], index)
     if index == 0:
         return stage_fn
     hop = redists[index - 1]
     avoid = tuple(d + off for d in stages[index].fft_dims)
 
-    def run(x: jax.Array) -> jax.Array:
-        return redistribute(x, hop, n_chunks=spec.chunk_schedule[index - 1],
+    def run(repro_fft_in: jax.Array) -> jax.Array:
+        return redistribute(repro_fft_in, hop,
+                            n_chunks=spec.chunk_schedule[index - 1],
                             then=stage_fn, spatial_offset=off,
                             avoid_dims=avoid, hop_index=index - 1)
 
@@ -484,11 +510,13 @@ def _local_segment(spec: PipelineSpec, index: int) -> Callable:
 
 
 def build_segment(mesh: Mesh, spec: PipelineSpec, index: int) -> Callable:
-    """shard_map one stage segment over the mesh.  jit-compatible."""
-    return shard_map(_local_segment(spec, index), mesh=mesh,
-                     in_specs=segment_in_spec(spec, index),
-                     out_specs=segment_out_spec(spec, index),
-                     check_vma=False)
+    """shard_map one stage segment over the mesh.  jit-compatible; named
+    :func:`program_name` with the segment's index."""
+    fn = shard_map(_local_segment(spec, index), mesh=mesh,
+                   in_specs=segment_in_spec(spec, index),
+                   out_specs=segment_out_spec(spec, index),
+                   check_vma=False)
+    return _named(fn, program_name(spec, index))
 
 
 def segment_structs(mesh: Mesh, spec: PipelineSpec,

@@ -16,6 +16,11 @@ executor's stage-at-a-time lowering) live in the same LRU cache: the
 so a plan's fused executable and each of its segments are distinct entries
 evicted under one global bound.
 
+Beneath both sits XLA: ``xla_compile_stats`` counts every jaxpr trace,
+backend compile and persistent-cache load of the process from JAX's own
+monitoring events (listeners registered once, on import), so a caller can
+tell a steady loop, which adds none, from one that recompiles.
+
 ``TuningCache`` is the second, *persistent* layer: compiled executables
 cannot survive the process, but the autotuner's **decisions** (which decomp
 / backend / n_chunks won for a given problem key) can, as JSON on disk — the
@@ -30,6 +35,8 @@ import threading
 import time
 from collections import OrderedDict
 from typing import Any, Callable, Dict, Hashable, Optional, Sequence, Tuple
+
+from jax import monitoring as jax_monitoring
 
 try:  # POSIX advisory file locking for cross-process cache merging
     import fcntl
@@ -51,7 +58,6 @@ def env_capacity(var: str, default: int) -> int:
 class PlanEntry:
     executable: Any          # compiled jax executable (or jitted fn)
     build_time_s: float      # wall time spent planning (compile)
-    hits: int = 0
 
 
 class PlanCache:
@@ -85,7 +91,6 @@ class PlanCache:
         with self._lock:
             entry = self._plans.get(key)
             if entry is not None:
-                entry.hits += 1
                 self.hits += 1
                 self._plans.move_to_end(key)
                 return entry
@@ -106,7 +111,6 @@ class PlanCache:
                     self._plans.popitem(last=False)
                     self.evictions += 1
             else:
-                entry.hits += 1
                 self.hits += 1
             self._plans.move_to_end(key)
         return entry
@@ -138,6 +142,52 @@ class PlanCache:
 
 # Process-global default cache (mirrors the paper's per-process plan store).
 GLOBAL_PLAN_CACHE = PlanCache()
+
+
+# ---------------------------------------------------------------------------
+# XLA compile counter: every trace, compile and persistent-cache load of the
+# process, from JAX's own monitoring events (their durations included, so
+# this module calls no clock of its own).
+# ---------------------------------------------------------------------------
+
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_XLA_LOCK = threading.Lock()
+_XLA_COUNTS = {"traces": 0, "backend": 0, "cache_loads": 0,
+               "compile_s": 0.0}
+
+
+def _on_xla_duration(event: str, duration_secs: float, **_: Any) -> None:
+    with _XLA_LOCK:
+        if event == _TRACE_EVENT:
+            _XLA_COUNTS["traces"] += 1
+        elif event == _COMPILE_EVENT:
+            _XLA_COUNTS["backend"] += 1
+            _XLA_COUNTS["compile_s"] += duration_secs
+
+
+def _on_xla_event(event: str, **_: Any) -> None:
+    if event == _CACHE_HIT_EVENT:
+        with _XLA_LOCK:
+            _XLA_COUNTS["cache_loads"] += 1
+
+
+def xla_compile_stats() -> Dict[str, Any]:
+    """Process-wide counts since ``repro.core`` was imported: ``traces``
+    (jaxprs traced for a jit), ``compiles`` (XLA backend compiles, cache
+    loads excluded), ``cache_loads`` (executables read from the persistent
+    compilation cache) and ``compile_s`` (seconds in backend compiles and
+    cache loads, as JAX reports them)."""
+    with _XLA_LOCK:
+        counts = dict(_XLA_COUNTS)
+    backend = counts.pop("backend")
+    counts["compiles"] = backend - counts["cache_loads"]
+    return counts
+
+
+jax_monitoring.register_event_duration_secs_listener(_on_xla_duration)
+jax_monitoring.register_event_listener(_on_xla_event)
 
 
 @dataclasses.dataclass(frozen=True)

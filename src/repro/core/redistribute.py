@@ -92,18 +92,23 @@ def redistribute(block: jax.Array, hop, *,
     touches, which the chunk dim must avoid.  ``n_chunks`` is this hop's
     entry of the pipeline's per-hop ``chunk_schedule`` — each hop chooses
     its own chunk dim and clamps its own count, so heterogeneous schedules
-    need no coordination here.  ``hop_index`` only labels the trace-time
-    warnings (``pipeline.make_spec`` records spec-time clamps).
+    need no coordination here.  ``hop_index`` names the hop's device
+    scope ``hop<i>`` and labels the trace-time warnings
+    (``pipeline.make_spec`` records spec-time clamps).
     """
     hop = _as_hop(hop)
     tag = f"hop {hop_index}" if hop_index is not None else "this hop"
+    # The exchange, and only it, runs under the device scope hop<i>: the
+    # fused ``then`` carries its own stage<k> scope, so no op is in both.
+    scope = f"hop{hop_index}" if hop_index is not None else "hop"
 
     def a2a(x: jax.Array) -> jax.Array:
-        for mv in hop.moves:
-            x = lax.all_to_all(x, mv.mesh_axis,
-                               split_axis=mv.split_dim + spatial_offset,
-                               concat_axis=mv.concat_dim + spatial_offset,
-                               tiled=True)
+        with jax.named_scope(scope):
+            for mv in hop.moves:
+                x = lax.all_to_all(x, mv.mesh_axis,
+                                   split_axis=mv.split_dim + spatial_offset,
+                                   concat_axis=mv.concat_dim + spatial_offset,
+                                   tiled=True)
         return x
 
     if n_chunks <= 1:
@@ -133,12 +138,14 @@ def redistribute(block: jax.Array, hop, *,
     # independent dataflow island, which is exactly what lets the compiler
     # overlap collective k+1 with compute k.  A fori_loop would serialize
     # them by construction.
-    pieces = jnp.split(block, eff_chunks, axis=chunk_dim)
+    with jax.named_scope(scope):
+        pieces = jnp.split(block, eff_chunks, axis=chunk_dim)
     outs = []
     for piece in pieces:
         t = a2a(piece)
         outs.append(then(t) if then is not None else t)
-    return jnp.concatenate(outs, axis=chunk_dim)
+    with jax.named_scope(scope):
+        return jnp.concatenate(outs, axis=chunk_dim)
 
 
 def transpose_cost_bytes(local_shape, dtype_bytes: int, axis_size: int) -> int:
