@@ -9,9 +9,9 @@ Three interchangeable backends (``LOCAL_BACKENDS``):
   separate real/imag planes.  This is the TPU-native formulation (MXU work
   instead of VPU butterflies) expressed as pure jnp ops.
 * ``"pallas"`` — the same four-step algorithm as an explicit Pallas kernel
-  (``kernels/fft_matmul.py``, wrapped by ``kernels/ops.py``), with the DCT
-  phase twiddle fused into its matmul operand.  On the CPU platform it runs
-  in interpret mode so tests stay hermetic.
+  (``kernels/fft_matmul.py``, wrapped by ``kernels/ops.py``), with a real
+  operand's DCT phase twiddle fused into its matmul operand.  On the CPU
+  platform it runs in interpret mode so tests stay hermetic.
 
 The matmul and pallas backends contract at ``MATMUL_PRECISION``
 (``kernels/precision.py``).
@@ -204,6 +204,20 @@ def _irfft(x: jax.Array, axis: int, n: int, backend: str) -> jax.Array:
 #   dct2(x)[k] = 2 sum_n x[n] cos(pi k (2n+1) / (2N))
 #   dct3(x)[k] = x[0] + 2 sum_{n>=1} x[n] cos(pi n (2k+1) / (2N))
 #   dct3(dct2(x)) = 2N x
+#
+# A complex operand (a bounded dim transformed after a periodic C2C stage,
+# e.g. the (Periodic, Periodic, Bounded) Poisson topology) means
+# R2R(Re x) + i R2R(Im x).  Both identities are C-linear, so one complex
+# FFT per line serves both planes:
+#   DCT-II:  V = FFT(shuffle(x)) and, with p_k = exp(-i pi k / 2N),
+#            dct2(Re x) + i dct2(Im x) = p_k V_k + conj(p_k) V_{(N-k) mod N};
+#            for real x, V is Hermitian and this is the real path's
+#            2 Re(p_k V_k).
+#   DCT-III: spec(x) = (x - i shifted(x)) exp(i pi k / 2N) is C-linear and
+#            Hermitian for real x, so IFFT(spec(x)) is dct3(Re x) +
+#            i dct3(Im x) before the unshuffle: the real path's real part,
+#            kept complex.
+# DST-II/III wrap these with sign flips and reversals, also C-linear.
 # ---------------------------------------------------------------------------
 
 def _dct2(x: jax.Array, axis: int, backend: str) -> jax.Array:
@@ -215,14 +229,21 @@ def _dct2(x: jax.Array, axis: int, backend: str) -> jax.Array:
     cdt = jnp.result_type(v.dtype, jnp.complex64)
     k = jnp.arange(n)
     phase = jnp.exp(-1j * jnp.pi * k / (2.0 * n)).astype(cdt)
-    if backend == "pallas":
+    if jnp.iscomplexobj(xm):
+        # One FFT for both planes; the epilogue pairs V_k with V_{(N-k) mod N}
+        # so it is no per-element twiddle and stays outside the kernel.
+        vf = _c2c(v, -1, inverse=False, backend=backend)
+        mirror = jnp.roll(vf[..., ::-1], 1, axis=-1)
+        out = phase * vf + jnp.conj(phase) * mirror
+    elif backend == "pallas":
         # Fused epilogue: the kernel applies the DCT phase in-register
         # instead of a separate elementwise pass over the FFT output.
         from repro.kernels import ops
         pv = ops.fft1d(v.astype(cdt), -1, twiddle=phase)
+        out = 2.0 * jnp.real(pv)
     else:
         pv = phase * _c2c(v.astype(cdt), -1, inverse=False, backend=backend)
-    out = 2.0 * jnp.real(pv)
+        out = 2.0 * jnp.real(pv)
     return jnp.moveaxis(out.astype(x.dtype), -1, ax)
 
 
@@ -236,7 +257,8 @@ def _dct3(x: jax.Array, axis: int, backend: str) -> jax.Array:
     shifted = jnp.concatenate([xm[..., :1] * 0, xm[..., :0:-1]], axis=-1)
     spec = (xm - 1j * shifted) * phase
     v = _c2c(spec, -1, inverse=True, backend=backend) * n
-    v = jnp.real(v)
+    if not jnp.iscomplexobj(xm):
+        v = jnp.real(v)
     out = jnp.zeros_like(v)
     half = (n + 1) // 2
     out = out.at[..., 0::2].set(v[..., :half])
@@ -283,11 +305,10 @@ def apply_1d(x: jax.Array, axis: int, kind: str, *, backend: str = "xla",
         fn = {"dct2": _dct2, "dct3": _dct3,
               "dst2": _dst2, "dst3": _dst3}[kind]
         if jnp.iscomplexobj(x):
-            # R2R transforms are linear over R: apply to planes separately
-            # (needed when a C2C stage precedes a bounded-dim DCT stage,
-            # e.g. the (Periodic, Periodic, Bounded) Poisson topology).
-            return jax.lax.complex(fn(jnp.real(x), axis, backend),
-                                   fn(jnp.imag(x), axis, backend))
+            # One complex FFT per line for both planes (see the R2R
+            # identities above), under the device scope ``r2r_complex``.
+            with jax.named_scope("r2r_complex"):
+                return fn(x, axis, backend)
         return fn(x, axis, backend)
     raise ValueError(f"unknown transform kind {kind!r}")
 

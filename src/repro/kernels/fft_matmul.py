@@ -30,8 +30,8 @@ free: only a last-axis transform needs a transpose (``kernels/ops.py``).
 
 ``M_k1[k2, m2] = e[k1 + N1*k2] * W_N2^{m2 k2} * W_N^{m2 k1} * s`` folds the
 four-step twiddle, the optional output twiddle ``e`` (the DCT-II phase of
-``transforms._dct2``) and the inverse's 1/N into the matmul operand, so
-neither epilogue costs a pass over the data.
+``transforms._dct2`` on a real operand) and the inverse's 1/N into the
+matmul operand, so neither epilogue costs a pass over the data.
 
 **Precision.**  f32 contractions use ``MATMUL_PRECISION``
 (``kernels/precision.py``, ``HIGHEST``: the MXU's f32 path); the default

@@ -4,7 +4,7 @@
 transform along ``axis`` using the MXU four-step kernel.  They are the
 routing target of ``backend="pallas"``: ``core/transforms.apply_1d``
 dispatches every C2C line of the pallas backend here, with the DCT-II/DST-II
-phase fused into the kernel (``twiddle=``).
+phase of a real operand fused into the kernel (``twiddle=``).
 
 ``interpret`` defaults to ``None`` = "interpret on the CPU platform only":
 there (the test suite, CI) the kernel body executes as traced jax ops; on

@@ -502,3 +502,53 @@ print("diff", float(np.max(np.abs(np.asarray(phi_pre) - np.asarray(phi_jit)))))
     assert vals["compiled_plans"] == "1"
     assert vals["jit_no_cache"] == "1"
     assert float(vals["diff"]) < 1e-5
+
+
+def test_complex_operand_dct_stage_runs_one_fft_per_line(cpu_mesh,
+                                                        monkeypatch):
+    """A DCT stage after periodic C2C stages sees a complex operand and
+    traces one C2C per line for both planes: a PPB solve traces 6 local
+    C2Cs (3 per direction; 8 when each plane had its own), a C2C plan 3 per
+    direction.  Only such a stage carries the ``r2r_complex`` scope."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import PoissonSolver, plan_fft, transforms
+    from repro.core.pipeline import build_pipeline
+
+    grid = (16, 16, 16)
+    ppb = PoissonSolver(cpu_mesh, grid, topology=("periodic", "periodic",
+                                                  "bounded"),
+                        precompiled=False)
+    c2c = plan_fft(cpu_mesh, grid, precompiled=False)
+    dct = plan_fft(cpu_mesh, grid, kinds=("dct2",) * 3, precompiled=False)
+    traced = []
+    c2c_fn = transforms._c2c
+
+    def counting(x, axis, *, inverse, backend):
+        traced.append("inverse" if inverse else "forward")
+        return c2c_fn(x, axis, inverse=inverse, backend=backend)
+
+    monkeypatch.setattr(transforms, "_c2c", counting)
+    rhs = np.random.default_rng(0).standard_normal(grid).astype(np.float32)
+    jax.block_until_ready(ppb.solve(jnp.asarray(rhs)))
+    assert traced == ["forward"] * 3 + ["inverse"] * 3
+    traced.clear()
+    y = jax.block_until_ready(c2c.forward(jnp.asarray(rhs, jnp.complex64)))
+    assert traced == ["forward"] * 3
+    traced.clear()
+    jax.block_until_ready(c2c.inverse(y))
+    assert traced == ["inverse"] * 3
+
+    def lowered(plan, inverse):
+        struct = plan.inv_in_struct if inverse else plan.in_struct
+        fn = build_pipeline(cpu_mesh, plan.pipeline_spec(inverse=inverse))
+        return jax.jit(fn).lower(struct).as_text(debug_info=True)
+
+    # The DCT axis is transformed last forward (stage 2), first inverse.
+    assert "/stage2/r2r_complex/" in lowered(ppb.plan, False)
+    assert "/stage0/r2r_complex/" in lowered(ppb.plan, True)
+    assert str(dct.in_struct.dtype) == "float32"
+    for plan in (c2c, dct):
+        for inverse in (False, True):
+            assert "r2r_complex" not in lowered(plan, inverse)

@@ -43,6 +43,28 @@ def naive_dst2(x, axis):
     return np.moveaxis(x @ mat.T, -1, axis)
 
 
+def naive_dct3(x, axis):
+    x = np.moveaxis(np.asarray(x, np.float64), axis, -1)
+    n = x.shape[-1]
+    k, m = np.arange(n), np.arange(n)
+    mat = 2 * np.cos(np.pi * np.outer(2 * k + 1, m) / (2 * n))
+    mat[:, 0] = 1.0
+    return np.moveaxis(x @ mat.T, -1, axis)
+
+
+def naive_dst3(x, axis):
+    x = np.moveaxis(np.asarray(x, np.float64), axis, -1)
+    n = x.shape[-1]
+    k, m = np.arange(n), np.arange(n)
+    mat = 2 * np.sin(np.pi * np.outer(2 * k + 1, m + 1) / (2 * n))
+    mat[:, -1] = (-1.0) ** k
+    return np.moveaxis(x @ mat.T, -1, axis)
+
+
+NAIVE_R2R = {"dct2": naive_dct2, "dct3": naive_dct3,
+             "dst2": naive_dst2, "dst3": naive_dst3}
+
+
 @pytest.mark.parametrize("n", [4, 8, 12, 16, 30, 64, 128])
 @pytest.mark.parametrize("backend", ["xla", "matmul"])
 def test_c2c_matches_naive(n, backend):
@@ -92,14 +114,42 @@ def test_r2r_roundtrip(fwd, inv):
     np.testing.assert_allclose(xb, x, rtol=1e-4, atol=1e-4)
 
 
-def test_r2r_complex_input_planes():
-    """DCT of complex input = DCT(re) + i DCT(im) (Poisson PPB path)."""
-    n = 8
-    x = (rng.standard_normal((2, n)) + 1j * rng.standard_normal((2, n))
+#: (shape, axis): the unbatched last-axis line of the PPB path's first
+#: form, and a batched operand transformed along a middle axis.
+COMPLEX_LAYOUTS = {"last": ((2, None), -1), "middle": ((3, None, 5), 1)}
+
+
+def _complex_operand(layout, n):
+    shape, axis = COMPLEX_LAYOUTS[layout]
+    shape = tuple(n if d is None else d for d in shape)
+    x = (rng.standard_normal(shape) + 1j * rng.standard_normal(shape)
          ).astype(np.complex64)
-    got = np.asarray(apply_1d(jnp.asarray(x), -1, "dct2"))
-    ref = naive_dct2(x.real, -1) + 1j * naive_dct2(x.imag, -1)
-    np.testing.assert_allclose(got, ref, rtol=2e-4, atol=1e-3)
+    return x, axis
+
+
+@pytest.mark.parametrize("layout", sorted(COMPLEX_LAYOUTS))
+@pytest.mark.parametrize("n", [7, 8, 12])
+@pytest.mark.parametrize("backend", ["xla", "matmul", "pallas"])
+@pytest.mark.parametrize("kind", sorted(NAIVE_R2R))
+def test_r2r_complex_input_planes(kind, backend, n, layout):
+    """R2R of a complex operand = R2R(re) + i R2R(im), from one complex FFT
+    per line (the PPB path: a DCT stage after periodic C2C stages)."""
+    x, axis = _complex_operand(layout, n)
+    got = apply_1d(jnp.asarray(x), axis, kind, backend=backend)
+    assert got.dtype == jnp.complex64 and got.shape == x.shape
+    ref = NAIVE_R2R[kind](x.real, axis) + 1j * NAIVE_R2R[kind](x.imag, axis)
+    np.testing.assert_allclose(np.asarray(got), ref, rtol=2e-4, atol=1e-3)
+
+
+@pytest.mark.parametrize("layout", sorted(COMPLEX_LAYOUTS))
+@pytest.mark.parametrize("backend", ["xla", "matmul", "pallas"])
+@pytest.mark.parametrize("fwd,inv", [("dct2", "dct3"), ("dst2", "dst3")])
+def test_r2r_complex_roundtrip(fwd, inv, backend, layout):
+    n = 12
+    x, axis = _complex_operand(layout, n)
+    y = apply_1d(jnp.asarray(x), axis, fwd, backend=backend)
+    xb = np.asarray(apply_1d(y, axis, inv, backend=backend)) / (2 * n)
+    np.testing.assert_allclose(xb, x, rtol=1e-4, atol=1e-4)
 
 
 def test_factorize():
