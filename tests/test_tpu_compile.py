@@ -45,10 +45,11 @@ def _largest_supported_length():
 
 
 @pytest.mark.parametrize("n,variant", [
-    (n, variant) for n in (256, 512, 1024, 2048)
+    (n, variant) for n in (256, 480, 512, 1024, 2048)
     for variant in ("plain", "twiddle", "inverse")] + [("largest", "plain")])
 def test_pallas_kernel_compiles_for_v5e(topo, n, variant):
-    """The FFT kernel compiles with Mosaic at the paper's line lengths and
+    """The FFT kernel compiles with Mosaic at the paper's line lengths
+    (480 = 2^5 * 3 * 5 split (4, 120), the powers of two (N/128, 128)) and
     at the longest line it accepts (``supports_length``: 3249 = 57 * 57),
     forward, inverse and with the twiddle folded in, and really is a TPU
     kernel."""
@@ -82,6 +83,36 @@ def test_pallas_kernel_compiles_for_v5e(topo, n, variant):
 def test_c2c_plan_fits_v5e_hbm(topo, mesh_shape, n, chunks):
     """A pencil C2C plan at the paper's grids compiles for v5e chips and
     its arguments, output and temporaries fit one chip's HBM."""
+    mem = _c2c_plan(topo, mesh_shape, n, chunks, "xla").memory_analysis()
+    per_chip = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes)
+    assert mem.argument_size_in_bytes == 8 * n**3 // int(np.prod(mesh_shape))
+    assert per_chip < HBM_PER_CHIP, f"{per_chip / GiB:.2f} GiB per chip"
+
+
+@pytest.mark.parametrize("backend", ["xla", "matmul", "pallas"])
+def test_mixed_radix_plan_fits_v5e_hbm(topo, backend, monkeypatch):
+    """The 480^3 C2C plan (480 = 2^5 * 3 * 5) compiles for one v5e chip on
+    every local backend and fits its HBM; the pallas plan runs the kernel.
+    Its minor axis is laid out padded to a multiple of 128 lanes, so an
+    argument takes more than 8 * 480^3 bytes."""
+    from repro.kernels import ops
+
+    # The process's platform is the CPU, where the kernel would run
+    # interpreted: compile it with Mosaic, as the chip does.
+    monkeypatch.setattr(ops, "interpret_mode", lambda: False)
+    compiled = _c2c_plan(topo, (1, 1), 480, 1, backend)
+    mem = compiled.memory_analysis()
+    per_chip = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes)
+    assert mem.argument_size_in_bytes == 8 * 480 * 480 * 512
+    assert per_chip < HBM_PER_CHIP, f"{per_chip / GiB:.2f} GiB per chip"
+    assert ("tpu_custom_call" in compiled.as_text()) == (backend == "pallas")
+
+
+def _c2c_plan(topo, mesh_shape, n, chunks, backend):
+    """The forward pencil C2C plan of an n^3 complex64 grid, compiled for
+    the described chips."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh
@@ -93,12 +124,7 @@ def test_c2c_plan_fits_v5e_hbm(topo, mesh_shape, n, chunks):
     mesh = Mesh(np.array(topo.devices[:count]).reshape(mesh_shape),
                 ("data", "model"))
     dec = make_decomposition("pencil", ("data", "model"), 3)
-    spec = make_spec(mesh, (n,) * 3, dec, ("fft",) * 3, backend="xla",
+    spec = make_spec(mesh, (n,) * 3, dec, ("fft",) * 3, backend=backend,
                      n_chunks=chunks)
     arg = input_struct(mesh, spec, (), jnp.complex64)
-    compiled = jax.jit(build_pipeline(mesh, spec)).lower(arg).compile()
-    mem = compiled.memory_analysis()
-    per_chip = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-                + mem.temp_size_in_bytes)
-    assert mem.argument_size_in_bytes == 8 * n**3 // count
-    assert per_chip < HBM_PER_CHIP, f"{per_chip / GiB:.2f} GiB per chip"
+    return jax.jit(build_pipeline(mesh, spec)).lower(arg).compile()
