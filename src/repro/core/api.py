@@ -22,14 +22,19 @@ directly.  Introspection comes along for free:
   donates the input buffer to the computation.
 * ``plan.in_struct`` / ``plan.out_struct`` — shape/dtype/sharding of the
   forward input/output (R2C frequency padding included).
-* ``plan.describe()`` — chosen decomposition, backend, n_chunks, and the
-  tuner's predicted vs. measured times.
+* ``plan.describe()`` — chosen decomposition, backend and why, n_chunks,
+  and the tuner's predicted vs. measured times.
 
 **Autotuning** (the paper's thesis): pass ``tuning=`` instead of
 hand-picking the knobs:
 
 * ``tuning="off"``        (default) use explicit ``decomp``/``backend``/
-  ``n_chunks`` as given;
+  ``n_chunks`` as given.  With no ``backend=``, the local backend follows
+  one rule (``transforms.default_backend``): the Pallas kernel when the
+  mesh's devices are TPUs, the kernel takes every line length in the
+  plan's dtype, and no kind is R2C; ``"xla"`` otherwise (on the CPU, for
+  lines over the kernel's VMEM, for float64, for R2C).  ``describe()``
+  states the reason and ``plan_cache_stats()["backend"]`` counts it;
 * ``tuning="heuristic"``  rank every valid plan with the calibrated
   LogP/roofline perf model and take the argmin — no timing runs, no disk;
 * ``tuning="auto"``       additionally *measure* the model's top-k
@@ -163,8 +168,11 @@ class DistributedFFT:
                  inv_spec: PipelineSpec, *,
                  batch_shape: Tuple[int, ...] = (), dtype=jnp.complex64,
                  tuned: Optional[TunedPlan] = None, tuning: str = "off",
-                 precompiled: bool = True, shared: bool = False):
+                 precompiled: bool = True, shared: bool = False,
+                 backend_why: str = ""):
         self.mesh = mesh
+        # Why the plan runs its local backend (``describe()``).
+        self.backend_why = backend_why or fwd_spec.backend
         self._fwd_spec = fwd_spec
         self._inv_spec = inv_spec
         self.batch_shape = tuple(batch_shape)
@@ -310,6 +318,7 @@ class DistributedFFT:
             f"  schedule: {decomp} over {self.mesh_axes}, "
             f"backend={self.backend}, n_chunks={chunks} "
             f"(tuning={self.tuning!r})",
+            f"  backend: {self.backend_why}",
             f"  tuner: {tuned_line}",
             f"  in:  {self._in_struct.shape} {self._in_struct.dtype} "
             f"{self._fwd_spec.in_spec()}",
@@ -634,7 +643,15 @@ def plan_fft(mesh: Mesh, grid: Sequence[int], *,
         raise ValueError(
             f"plan_fft: unknown backend {backend!r}; supported backends: "
             f"{', '.join(transforms.LOCAL_BACKENDS)}")
-    backend = backend if backend is not None else "xla"
+    platform = mesh.devices.flat[0].platform
+    if tuning != "off":
+        cause = "tuner"
+    elif backend is not None:
+        cause, backend_why = "caller", f"{backend}: named by the caller"
+    else:
+        cause = "default"
+        backend, backend_why = transforms.default_backend(platform, grid,
+                                                          dtype, kinds)
     chunk_schedule = None
     if n_chunks is None:
         n_chunks = 1
@@ -662,9 +679,11 @@ def plan_fft(mesh: Mesh, grid: Sequence[int], *,
                                mode=tuning, cache=tune_cache,
                                default=default, objective=tune_objective)
 
+    if cause == "tuner":
+        backend_why = f"{tuned.backend}: chosen by tuning={tuning!r}"
     if tuned.backend == "pallas":
         from repro.kernels.ops import unsupported  # deferred: loads Pallas
-        why = unsupported(grid, dtype)
+        why = unsupported(grid, dtype, platform)
         if why:
             raise ValueError(f"plan_fft: backend='pallas' {why}; use "
                              f"backend='xla' or 'matmul'")
@@ -686,7 +705,9 @@ def plan_fft(mesh: Mesh, grid: Sequence[int], *,
                          batch_spec=batch_spec)
     plan = DistributedFFT(mesh, fwd_spec, inv_spec, batch_shape=batch_shape,
                           dtype=dtype, tuned=tuned, tuning=tuning,
-                          precompiled=precompiled)
+                          precompiled=precompiled, backend_why=backend_why)
+    with _BACKEND_COUNTS_LOCK:
+        _BACKEND_COUNTS[tuned.backend][cause] += 1
     if validate != "off":
         report = plan.verify(tune_cache=tune_cache,
                              strict=validate == "strict")
@@ -764,6 +785,13 @@ def plan_memo_stats() -> Dict[str, int]:
                 **_MEMO_COUNTERS}
 
 
+# Plans built per local backend and per cause: the default rule
+# (``transforms.default_backend``), the caller's ``backend=``, or tuning.
+_BACKEND_COUNTS = {b: {"default": 0, "caller": 0, "tuner": 0}
+                   for b in transforms.LOCAL_BACKENDS}
+_BACKEND_COUNTS_LOCK = threading.Lock()
+
+
 def plan_cache_stats() -> Dict[str, Dict[str, Any]]:
     """Public counters of both in-process plan-caching layers, and of XLA.
 
@@ -776,12 +804,18 @@ def plan_cache_stats() -> Dict[str, Dict[str, Any]]:
     program's and others': ``traces``, ``compiles`` (cache loads
     excluded), ``cache_loads`` from the persistent compilation cache and
     ``compile_s`` (:func:`~repro.core.plan.xla_compile_stats`); a steady
-    loop adds nothing to them.
+    loop adds nothing to them.  ``backend`` — plans built per local
+    backend, split by what chose it: ``default`` (the rule of
+    :func:`~repro.core.transforms.default_backend`), ``caller``
+    (``backend=``) or ``tuner``.
     """
     from .plan import GLOBAL_PLAN_CACHE, xla_compile_stats
+    with _BACKEND_COUNTS_LOCK:
+        backends = {b: dict(c) for b, c in _BACKEND_COUNTS.items()}
     return {"compiled": GLOBAL_PLAN_CACHE.stats(),
             "memo": plan_memo_stats(),
-            "xla": xla_compile_stats()}
+            "xla": xla_compile_stats(),
+            "backend": backends}
 
 
 def _wrapper_plan(mesh: Mesh, grid, kinds, batch_shape, dtype, decomp,

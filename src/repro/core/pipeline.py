@@ -368,12 +368,28 @@ def _local_pipeline(spec: PipelineSpec) -> Callable:
     return run
 
 
+def _for_devices(mesh: Mesh, spec: PipelineSpec, fn: Callable) -> Callable:
+    """``fn`` traced for ``mesh``'s platform: the Pallas kernel runs
+    interpreted only for CPU devices, so a plan for TPU devices compiles it
+    with Mosaic whatever the process's default backend."""
+    if spec.backend != "pallas":
+        return fn
+    from repro.kernels import ops  # deferred: loads Pallas
+    platform = mesh.devices.flat[0].platform
+
+    def run(repro_fft_in: jax.Array) -> jax.Array:
+        with ops.for_platform(platform):
+            return fn(repro_fft_in)
+
+    return run
+
+
 def build_pipeline(mesh: Mesh, spec: PipelineSpec) -> Callable:
     """shard_map the local pipeline over the mesh.  jit-compatible; named
     :func:`program_name`."""
-    fn = shard_map(_local_pipeline(spec), mesh=mesh,
-                   in_specs=spec.in_spec(), out_specs=spec.out_spec(),
-                   check_vma=False)
+    fn = shard_map(_for_devices(mesh, spec, _local_pipeline(spec)),
+                   mesh=mesh, in_specs=spec.in_spec(),
+                   out_specs=spec.out_spec(), check_vma=False)
     return _named(fn, program_name(spec))
 
 
@@ -512,8 +528,8 @@ def _local_segment(spec: PipelineSpec, index: int) -> Callable:
 def build_segment(mesh: Mesh, spec: PipelineSpec, index: int) -> Callable:
     """shard_map one stage segment over the mesh.  jit-compatible; named
     :func:`program_name` with the segment's index."""
-    fn = shard_map(_local_segment(spec, index), mesh=mesh,
-                   in_specs=segment_in_spec(spec, index),
+    fn = shard_map(_for_devices(mesh, spec, _local_segment(spec, index)),
+                   mesh=mesh, in_specs=segment_in_spec(spec, index),
                    out_specs=segment_out_spec(spec, index),
                    check_vma=False)
     return _named(fn, program_name(spec, index))

@@ -13,6 +13,9 @@ Three interchangeable backends (``LOCAL_BACKENDS``):
   operand's DCT phase twiddle fused into its matmul operand.  On the CPU
   platform it runs in interpret mode so tests stay hermetic.
 
+A plan whose caller names no backend takes :func:`default_backend`'s:
+the kernel on TPU devices wherever it takes the lines, else xla.
+
 The matmul and pallas backends contract at ``MATMUL_PRECISION``
 (``kernels/precision.py``).
 
@@ -42,6 +45,29 @@ ALL_KINDS = C2C_KINDS + R2C_KINDS + R2R_KINDS
 
 #: Every local-FFT backend ``apply_1d`` (and hence the tuner) accepts.
 LOCAL_BACKENDS = ("xla", "matmul", "pallas")
+
+
+def default_backend(platform: str, lengths: Tuple[int, ...], dtype,
+                    kinds: Tuple[str, ...]) -> Tuple[str, str]:
+    """The local backend of a plan whose caller names none, and why.
+
+    ``"pallas"`` where the kernel both applies and helps: the plan's
+    devices are TPUs (on a v5e the kernel runs a 512^3 or 480^3 C2C in
+    half of XLA's time), the kernel takes every line length in ``dtype``
+    (``kernels.ops.unsupported``), and no kind is R2C, which the kernel
+    computes as the full C2C and trims.  ``"xla"`` otherwise.
+    """
+    if platform != "tpu":
+        return "xla", f"xla: {platform} devices"
+    r2c = sorted({k for k in kinds if k in R2C_KINDS})
+    if r2c:
+        return "xla", f"xla: {'/'.join(r2c)} runs a full C2C on the kernel"
+    from repro.kernels.ops import unsupported  # deferred: loads Pallas
+    why = unsupported(lengths, dtype, platform)
+    if why:
+        return "xla", f"xla: the kernel {why}"
+    fit = ", ".join(str(n) for n in sorted(set(lengths)))
+    return "pallas", f"pallas: {platform}, lines {fit} fit"
 
 
 def factorize(n: int) -> Tuple[int, int]:

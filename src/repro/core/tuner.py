@@ -1,8 +1,10 @@
 """Plan autotuner: the runtime picks the schedule (the paper's thesis).
 
-The static API defaults (``decomp="pencil"``, ``backend="xla"``,
-``n_chunks=1``) are exactly the hard-coded knobs the paper argues a dynamic
-runtime should choose.  ``tune()`` closes that loop for one problem key
+The static API defaults (``decomp="pencil"``, ``n_chunks=1``, and the
+local backend of ``transforms.default_backend``: the Pallas kernel on TPU
+devices wherever it takes the lines and no kind is R2C, else ``"xla"``)
+are fixed choices of the kind the paper argues a dynamic runtime should
+make.  ``tune()`` closes that loop for one problem key
 (global grid, mesh geometry, transform kinds, dtype, batch shape):
 
 1. **enumerate** candidate plans — decomposition in {pencil, slab, hybrid}
@@ -69,6 +71,7 @@ from .pipeline import (PipelineSpec, chunk_sites, compile_pipeline,
 from .plan import (TunedPlan, TuningCache, global_tuning_cache,
                    parse_tuning_key, tuning_key)
 from .scheduler import choose_chunk_schedule
+from .transforms import default_backend
 
 # The tuner's full backend space — mirrors ``transforms.LOCAL_BACKENDS``.
 # "pallas" is the explicit MXU kernel (kernels/fft_matmul.py) with fused
@@ -543,10 +546,13 @@ def fits_device(cand: Candidate, grid: Tuple[int, ...], mesh: Mesh,
     return True
 
 
-def _default_candidate(cands: Sequence[Candidate]) -> Optional[Candidate]:
-    """The plan the static API would have used (baseline to never regress)."""
+def _default_candidate(cands: Sequence[Candidate],
+                       backend: str = "xla") -> Optional[Candidate]:
+    """The plan the static API would have used (baseline to never regress):
+    pencil, bulk, and ``backend``, the default rule's choice for the
+    problem (``transforms.default_backend``; xla off TPU devices)."""
     for cand in cands:
-        if cand.backend == "xla" and cand.n_chunks == 1 \
+        if cand.backend == backend and cand.n_chunks == 1 \
                 and cand.decomp == "pencil":
             return cand
     return cands[0] if cands else None
@@ -626,7 +632,8 @@ def tune(grid: Sequence[int], mesh: Mesh, *,
                          f"got {objective!r}")
     unrestricted = set(BACKENDS).issubset(set(backends)) and max_chunks is None
     from repro.kernels.ops import unsupported  # deferred: loads Pallas
-    if unsupported(grid, dtype):
+    platform = mesh.devices.flat[0].platform
+    if unsupported(grid, dtype, platform):
         # The kernel cannot run these lines here (f64 on a chip, or lines
         # too long for its VMEM): not a candidate.
         backends = tuple(b for b in backends if b != "pallas")
@@ -695,7 +702,8 @@ def tune(grid: Sequence[int], mesh: Mesh, *,
             break
         if fits(cand):
             survivors.append(cand)
-    baseline = _default_candidate(cands)
+    baseline = _default_candidate(
+        cands, default_backend(platform, grid, dtype, kinds)[0])
     if baseline is not None and baseline not in survivors and fits(baseline):
         survivors.append(baseline)
     if not survivors:

@@ -10,6 +10,9 @@ phase of a real operand fused into the kernel (``twiddle=``).
 there (the test suite, CI) the kernel body executes as traced jax ops; on
 every other platform the same call sites compile the Mosaic kernel, and a
 kernel the compiler refuses is an error, never a silent interpreter run.
+The platform is the one the program is built for: a plan sets it to its
+mesh's devices (:func:`for_platform`), so a plan for TPU devices compiles
+the Mosaic kernel even when the process's default backend is the CPU.
 Output dtype follows the input — complex64 in/out for single precision,
 complex128 end-to-end under ``jax.enable_x64`` (interpret mode only).
 :func:`unsupported` says, before anything is traced, which plans the kernel
@@ -17,8 +20,10 @@ cannot run; the planner and the tuner ask it.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import math
-from typing import Optional, Sequence
+from typing import Iterator, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -26,13 +31,34 @@ import jax.numpy as jnp
 from .fft_matmul import fft_cols, line_flops, supports_length, vmem_bytes
 from .precision import MATMUL_PRECISION
 
-__all__ = ["fft1d", "ifft1d", "interpret_mode", "line_flops",
-           "supports_dtype", "supports_length", "unsupported"]
+__all__ = ["fft1d", "for_platform", "ifft1d", "interpret_mode",
+           "line_flops", "platform", "supports_dtype", "supports_length",
+           "unsupported"]
+
+_PLATFORM: contextvars.ContextVar[Optional[str]] = contextvars.ContextVar(
+    "repro_kernel_platform", default=None)
+
+
+@contextlib.contextmanager
+def for_platform(name: str) -> Iterator[None]:
+    """Plan and trace the kernel for devices of platform ``name`` (``"tpu"``,
+    ``"cpu"``, ...) inside the block, whatever the default backend."""
+    token = _PLATFORM.set(name)
+    try:
+        yield
+    finally:
+        _PLATFORM.reset(token)
+
+
+def platform() -> str:
+    """The platform the kernel is built for: the innermost
+    :func:`for_platform`, else the process's default backend."""
+    return _PLATFORM.get() or jax.default_backend()
 
 
 def interpret_mode() -> bool:
-    """Whether the kernel runs interpreted here: on the CPU platform only."""
-    return jax.default_backend() == "cpu"
+    """Whether the kernel runs interpreted: on the CPU platform only."""
+    return platform() == "cpu"
 
 
 def supports_dtype(dtype) -> bool:
@@ -41,12 +67,15 @@ def supports_dtype(dtype) -> bool:
     return interpret_mode() or jnp.finfo(dtype).bits < 64
 
 
-def unsupported(lengths: Sequence[int], dtype) -> Optional[str]:
+def unsupported(lengths: Sequence[int], dtype,
+                on: Optional[str] = None) -> Optional[str]:
     """Why the kernel cannot run lines of these ``lengths`` in ``dtype``
-    on this platform, or ``None`` when it can."""
-    if not supports_dtype(dtype):
-        return (f"has no {jnp.dtype(dtype)} path on {jax.default_backend()} "
-                f"(Mosaic has no f64)")
+    on platform ``on`` (default :func:`platform`), or ``None`` when it
+    can."""
+    with for_platform(on or platform()):
+        if not supports_dtype(dtype):
+            return (f"has no {jnp.dtype(dtype)} path on {platform()} "
+                    f"(Mosaic has no f64)")
     too_long = sorted({n for n in lengths if not supports_length(n)})
     if too_long:
         return (f"does not take lines of length {too_long}: one kernel "

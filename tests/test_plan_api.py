@@ -58,6 +58,68 @@ def test_plan_introspection(cpu_mesh):
         assert token in rep, rep
 
 
+C2C3 = ("fft",) * 3
+PPB = ("fft", "fft", "dct2")
+
+
+@pytest.mark.parametrize("platform,grid,dtype,kinds,want", [
+    ("cpu", (512,) * 3, "complex64", C2C3, "xla"),
+    ("tpu", (480,) * 3, "complex64", C2C3, "pallas"),
+    ("tpu", (512,) * 3, "complex64", C2C3, "pallas"),
+    ("tpu", (512,) * 3, "float32", PPB, "pallas"),
+    ("tpu", (1024, 1024, 512), "complex64", C2C3, "pallas"),
+    ("tpu", (2048, 16, 16), "float32", C2C3, "pallas"),
+    ("tpu", (4096, 16, 16), "complex64", C2C3, "xla"),
+    ("tpu", (1031, 16, 16), "complex64", C2C3, "xla"),
+    ("tpu", (512,) * 3, "complex128", C2C3, "xla"),
+    ("tpu", (512,) * 3, "float64", PPB, "xla"),
+    ("tpu", (512,) * 3, "float32", ("rfft", "fft", "fft"), "xla"),
+])
+def test_default_backend_rule(platform, grid, dtype, kinds, want):
+    """With no ``backend=``, a plan takes the Pallas kernel on TPU devices
+    where the kernel takes every line (up to 2048, not 4096 or a prime as
+    large as 1031) in a dtype without f64, for C2C and R2R kinds; xla on
+    the CPU, for float64 and for R2C.  The reason names the cause."""
+    from repro.core.transforms import default_backend
+    backend, why = default_backend(platform, grid, dtype, kinds)
+    assert backend == want
+    assert why.startswith(f"{want}: ")
+    if platform == "cpu":
+        assert why == "xla: cpu devices"
+    elif want == "pallas":
+        assert why == "pallas: tpu, lines " + ", ".join(
+            str(n) for n in sorted(set(grid))) + " fit"
+    elif "rfft" in kinds:
+        assert "rfft" in why
+    elif dtype in ("complex128", "float64"):
+        assert f"no {dtype} path on tpu" in why
+    else:
+        assert f"lines of length [{grid[0]}]" in why
+
+
+@pytest.mark.parametrize("backend", [None, "xla", "matmul", "pallas"])
+def test_plan_backend_cause_is_counted_and_described(cpu_mesh, backend):
+    """On the CPU a plan built without ``backend=`` runs xla by the default
+    rule; an explicit ``backend=`` is kept.  ``plan_cache_stats()
+    ["backend"]`` counts each build under its backend and cause, and
+    ``describe()`` states the reason."""
+    from repro.core import plan_cache_stats, plan_fft
+    before = plan_cache_stats()["backend"]
+    plan = plan_fft(cpu_mesh, (8, 8, 8), backend=backend,
+                    precompiled=False)
+    after = plan_cache_stats()["backend"]
+    want, cause = (("xla", "default") if backend is None
+                   else (backend, "caller"))
+    assert plan.backend == want
+    assert after[want][cause] == before[want][cause] + 1
+    assert sum(map(sum, (c.values() for c in after.values()))) == \
+        sum(map(sum, (c.values() for c in before.values()))) + 1
+    reason = ("xla: cpu devices" if backend is None
+              else f"{backend}: named by the caller")
+    assert plan.backend_why == reason
+    assert f"  backend: {reason}" in plan.describe().splitlines()
+
+
 def test_plan_fft_validates_arguments(cpu_mesh):
     from repro.core import plan_fft
     with pytest.raises(ValueError, match="2 transform dims"):

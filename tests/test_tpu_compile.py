@@ -128,3 +128,50 @@ def _c2c_plan(topo, mesh_shape, n, chunks, backend):
                      n_chunks=chunks)
     arg = input_struct(mesh, spec, (), jnp.complex64)
     return jax.jit(build_pipeline(mesh, spec)).lower(arg).compile()
+
+
+@pytest.mark.parametrize("case", ["c2c512", "c2c480", "ppb512", "c2c1024x4"])
+def test_default_plan_runs_the_kernel_on_v5e(topo, case):
+    """With no ``backend=``, a plan for described v5e chips takes the Pallas
+    kernel at the benchmark's grids: 512^3 and 480^3 C2C and the 512^3 PPB
+    pressure solve on one chip, and the 1024^3 pencil inside ``shard_map``
+    on the 2x2 mesh.  Forward and inverse compile with the Mosaic kernel
+    as a TPU custom call and no XLA FFT, and their argument and output
+    bytes (the live arrays between calls) equal the xla plan's."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh
+
+    from repro.core.api import PoissonSolver, plan_fft
+    from repro.core.pipeline import build_pipeline
+
+    mesh_shape, n = ((2, 2), 1024) if case == "c2c1024x4" else ((1, 1), 512)
+    if case == "c2c480":
+        n = 480
+    mesh = Mesh(np.array(topo.devices[:int(np.prod(mesh_shape))])
+                .reshape(mesh_shape), ("data", "model"))
+
+    def plan(backend=None):
+        if case == "ppb512":
+            return PoissonSolver(mesh, (n,) * 3,
+                                 topology=("periodic", "periodic", "bounded"),
+                                 backend=backend, precompiled=False).plan
+        return plan_fft(mesh, (n,) * 3, backend=backend, precompiled=False)
+
+    def compiled(p, inverse):
+        arg = p.inv_in_struct if inverse else p.in_struct
+        lowered = jax.jit(build_pipeline(
+            mesh, p.pipeline_spec(inverse=inverse))).lower(arg)
+        return lowered.as_text(), lowered.compile()
+
+    default, xla = plan(), plan("xla")
+    assert (default.backend, xla.backend) == ("pallas", "xla")
+    assert default.backend_why.startswith(f"pallas: tpu, lines {n} fit")
+    for inverse in (False, True):
+        text, exe = compiled(default, inverse)
+        assert "stablehlo.fft" not in text
+        assert "tpu_custom_call" in exe.as_text()
+        _, ref = compiled(xla, inverse)
+        mem, ref_mem = exe.memory_analysis(), ref.memory_analysis()
+        assert (mem.argument_size_in_bytes, mem.output_size_in_bytes) == \
+            (ref_mem.argument_size_in_bytes, ref_mem.output_size_in_bytes)

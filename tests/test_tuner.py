@@ -104,6 +104,18 @@ def test_enumerate_includes_hybrids_for_3d(cpu_mesh):
     assert ((0,), (1,), (2,)) not in groups
 
 
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_default_candidate_follows_the_default_rule(cpu_mesh, backend):
+    """The tuner's never-regress baseline is the plan the static API
+    builds: pencil, bulk, on the backend the default rule picks (xla on
+    the CPU, the kernel on TPU devices where it takes the lines)."""
+    from repro.core.tuner import _default_candidate, enumerate_candidates
+    cands = enumerate_candidates((8, 8, 16), cpu_mesh, ("fft",) * 3)
+    base = _default_candidate(cands, backend)
+    assert (base.decomp, base.backend, base.n_chunks) == \
+        ("pencil", backend, 1)
+
+
 def test_enumerate_4d_on_2axis_mesh(cpu_mesh):
     """A 4-D grid on a 2-axis mesh has no pencil; slab + hybrids carry it."""
     from repro.core.tuner import enumerate_candidates
